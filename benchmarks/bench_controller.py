@@ -18,13 +18,14 @@ arrays.  Rows:
   -> decide -> apply scan throughput (ticks/s across the batch);
 * ``fused_loop_sharded_ticks_per_second_B{B}_D{D}`` — the mesh story
   (DESIGN.md §16): the same fused loop at fleet scale (B=4096 full run,
-  B=64 smoke) with the batch axis sharded over D emulated host devices,
-  measured in a subprocess because ``XLA_FLAGS=
-  --xla_force_host_platform_device_count`` must be set before jax
-  imports.  A ``_pinned_..._D1`` twin row runs the identical shard_map
-  program on a 1-device mesh; ``sharded_vs_pinned_ratio`` reports the
+  B=64 smoke) with the batch axis sharded over the D visible devices, in
+  this process (on a CPU host, emulate devices with ``XLA_FLAGS=
+  --xla_force_host_platform_device_count=D`` set before jax imports;
+  with one visible device these four rows are left out).
+  A ``_pinned_..._D1`` twin row runs the identical shard_map program on
+  a 1-device mesh; ``sharded_vs_pinned_ratio`` reports the
   device-parallel speedup (only meaningful when the host has cores to
-  back the emulated devices — the note records the core count);
+  back emulated devices — the note records the core count);
 * ``gain_topr_interpret_parity`` — Pallas top-R kernel vs jnp oracle in
   interpret mode on CPU (1.0 = exact take-for-take agreement);
 * ``decide_dense_ticks_per_second_B{B}`` /
@@ -45,10 +46,7 @@ arrays.  Rows:
 
 from __future__ import annotations
 
-import json
 import os
-import pathlib
-import subprocess
 import sys
 import time
 
@@ -77,6 +75,7 @@ def _scalar_schedulers(runner: ScenarioRunner):
 
 
 def run(smoke: bool = False) -> list[tuple[str, float, str]]:
+    import jax
     import jax.numpy as jnp
 
     rows: list[tuple[str, float, str]] = []
@@ -167,32 +166,17 @@ def run(smoke: bool = False) -> list[tuple[str, float, str]]:
     ))
     base_tps = n_ticks * b / t_loop
 
-    # --- sharded fused loop at fleet scale (subprocess: XLA_FLAGS) ------- #
-    b_shard, d = (64, 2) if smoke else (4096, 8)
-    info = _run_sharded_subprocess(b_shard, d, horizon, reps=2)
-    sharded_tps = info["ticks_per_s_sharded"]
-    pinned_tps = info["ticks_per_s_pinned"]
-    rows.append((
-        f"fused_loop_sharded_ticks_per_second_B{b_shard}_D{d}", sharded_tps,
-        f"scenario-ticks/s, batch axis shard_map'd over {d} emulated host "
-        f"devices ({info['n_ticks']} ticks)",
-    ))
-    rows.append((
-        f"fused_loop_pinned_ticks_per_second_B{b_shard}_D1", pinned_tps,
-        "same shard_map program on a 1-device mesh (the pinned baseline)",
-    ))
-    rows.append((
-        f"sharded_vs_pinned_ratio_B{b_shard}",
-        sharded_tps / max(pinned_tps, 1e-12),
-        f"x D={d} mesh vs 1-device mesh; host has {os.cpu_count()} core(s) "
-        "backing the emulated devices — parallel speedup needs real cores",
-    ))
-    rows.append((
-        f"sharded_vs_B{b}_throughput_ratio",
-        sharded_tps / max(base_tps, 1e-12),
-        f"x B={b_shard} sharded aggregate scenario-ticks/s vs this run's "
-        f"B={b} single-device row (ROADMAP's ~4.4k ticks/s reference)",
-    ))
+    # --- sharded fused loop at fleet scale, over the visible devices ----- #
+    b_shard = 64 if smoke else 4096
+    if len(jax.devices()) < 2:
+        # A 1-device "mesh" row would only repeat the pinned baseline.
+        print(
+            "bench_controller: sharded rows skipped, 1 device visible (set "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=D on a CPU host)",
+            file=sys.stderr,
+        )
+    else:
+        rows.extend(_sharded_rows(b_shard, b, base_tps, horizon))
 
     # --- §18 trigger-gated compacted decide vs dense --------------------- #
     rows.extend(_compaction_rows(smoke))
@@ -370,35 +354,43 @@ def _compaction_rows(smoke: bool) -> list[tuple[str, float, str]]:
 
 
 # --------------------------------------------------------------------------- #
-# Sharded rows run out-of-process: the emulated-device flag must be in
-# XLA_FLAGS before jax ever imports, which this (already-jax-importing)
-# process cannot retrofit.
+# Sharded rows run in this process over every visible device: one process
+# holds the chips.  Emulated CPU devices come from XLA_FLAGS=
+# --xla_force_host_platform_device_count=D, set before jax is imported.
 # --------------------------------------------------------------------------- #
-def _run_sharded_subprocess(b: int, d: int, horizon: float, reps: int) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={d}"
-    ).strip()
-    src = str(pathlib.Path(__file__).resolve().parent.parent / "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run(
-        [sys.executable, __file__, "--sharded-worker",
-         str(b), str(d), str(horizon), str(reps)],
-        env=env, capture_output=True, text=True, timeout=1800,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"sharded bench worker failed (rc={proc.returncode}):\n"
-            f"{proc.stderr[-2000:]}"
-        )
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+def _sharded_rows(
+    b_shard: int, b: int, base_tps: float, horizon: float
+) -> list[tuple[str, float, str]]:
+    info = _sharded_tps(b_shard, horizon, reps=2)
+    d = info["devices"]
+    sharded_tps = info["ticks_per_s_sharded"]
+    pinned_tps = info["ticks_per_s_pinned"]
+    return [
+        (
+            f"fused_loop_sharded_ticks_per_second_B{b_shard}_D{d}", sharded_tps,
+            f"scenario-ticks/s, batch axis shard_map'd over {d} "
+            f"{info['platform']} devices ({info['n_ticks']} ticks)",
+        ),
+        (
+            f"fused_loop_pinned_ticks_per_second_B{b_shard}_D1", pinned_tps,
+            "same shard_map program on a 1-device mesh (the pinned baseline)",
+        ),
+        (
+            f"sharded_vs_pinned_ratio_B{b_shard}",
+            sharded_tps / max(pinned_tps, 1e-12),
+            f"x D={d} mesh vs 1-device mesh; host has {os.cpu_count()} core(s) "
+            "backing emulated CPU devices — parallel speedup needs real cores",
+        ),
+        (
+            f"sharded_vs_B{b}_throughput_ratio",
+            sharded_tps / max(base_tps, 1e-12),
+            f"x B={b_shard} sharded aggregate scenario-ticks/s vs this run's "
+            f"B={b} single-device row (ROADMAP's ~4.4k ticks/s reference)",
+        ),
+    ]
 
 
-def _sharded_worker(argv: list[str]) -> None:
-    b, d, horizon, reps = (
-        int(argv[0]), int(argv[1]), float(argv[2]), int(argv[3])
-    )
+def _sharded_tps(b: int, horizon: float, reps: int) -> dict:
     import jax
 
     from repro.distributed.sharding import fleet_mesh
@@ -409,7 +401,8 @@ def _sharded_worker(argv: list[str]) -> None:
         for s in scenario_matrix(b, seed=5, horizon=horizon, warmup=5.0, dt=0.05)
     ]
     runner = ScenarioRunner(scens, tick_interval=5.0, backend="jax")
-    out: dict = {"b": b, "devices": len(jax.devices())}
+    d = len(jax.devices())
+    out: dict = {"b": b, "devices": d, "platform": jax.devices()[0].platform}
     for tag, nd in (("sharded", d), ("pinned", 1)):
         loop, n_ticks = ctl.make_fused_loop(
             runner.arrays, runner.static, runner._params(),
@@ -423,12 +416,9 @@ def _sharded_worker(argv: list[str]) -> None:
             ts.append(time.perf_counter() - t0)
         out[f"ticks_per_s_{tag}"] = n_ticks * b / min(ts)
         out["n_ticks"] = n_ticks
-    print(json.dumps(out))
+    return out
 
 
 if __name__ == "__main__":
-    if len(sys.argv) > 1 and sys.argv[1] == "--sharded-worker":
-        _sharded_worker(sys.argv[2:])
-    else:
-        for _name, _val, _note in run(smoke="--smoke" in sys.argv[1:]):
-            print(f"{_name},{_val},{_note}")
+    for _name, _val, _note in run(smoke="--smoke" in sys.argv[1:]):
+        print(f"{_name},{_val},{_note}")

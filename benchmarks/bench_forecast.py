@@ -132,7 +132,7 @@ def _twin_jit_agreement() -> float:
     from repro.forecast import forecast_rates, mpc_plan
     from repro.kernels.gain_topr import ops as topr_ops
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(42)
         b, n, w, hzn, k_hi = 4, 3, 12, 3, 32
         hist = rng.uniform(2.0, 20.0, (b, w, n))

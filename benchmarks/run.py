@@ -107,6 +107,9 @@ def persist(name: str, rows: list, smoke: bool) -> pathlib.Path:
 def main() -> None:
     # ``python -m benchmarks.run [suite] [--smoke]`` — smoke caps every
     # bench to seconds (CI drift gate); a suite name runs just that one.
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache(REPO_ROOT / ".jax_cache")
     args = [a for a in sys.argv[1:] if a != "--smoke"]
     smoke = "--smoke" in sys.argv[1:]
     only = args[0] if args else None

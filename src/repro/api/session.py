@@ -887,7 +887,7 @@ class ScenarioRunner:
         compact=None,
     ):
         from ..streaming.batchsim import BatchQueueSim
-        from ..streaming.scenarios import pack_allocations, pack_scenarios
+        from ..streaming.scenarios import map_distinct, pack_allocations, pack_scenarios
 
         self.scenarios = list(scenarios)
         self.tick_interval = tick_interval
@@ -921,7 +921,9 @@ class ScenarioRunner:
         self.sim = BatchQueueSim(
             self.arrays, backend=backend, interpret=interpret, force_kernel=force_kernel
         )
-        self.k = pack_allocations(self.scenarios, [s.plan_k0() for s in self.scenarios])
+        self.k = pack_allocations(
+            self.scenarios, map_distinct(self.scenarios, lambda s: s.plan_k0())
+        )
         self.static = ctl.ControllerStatic.from_graphs(
             [s.graph for s in self.scenarios],
             speed=[s.speed_vector() for s in self.scenarios],
@@ -1202,19 +1204,24 @@ class ScenarioRunner:
     def reports(self) -> list[ScenarioReport]:
         from ..core.allocator import InsufficientResourcesError, allocate
         from ..core.jackson import UnstableTopologyError
+        from ..streaming.scenarios import map_distinct
 
         res = self._fused_result if self._fused_result is not None else self.sim.result()
         a = self.arrays
         sojourns = res.sojourn(self.k, a.mu, a.group, a.alpha, a.speed,
                                ca2=a.ca2, cs2=a.cs2)
         sat = res.saturated(self.k, a.mu, a.group, a.alpha, a.speed)
-        out = []
-        for bi, s in enumerate(self.scenarios):
-            n = s.graph.n
+
+        def optimal_total(s):
             try:
-                optimal = allocate(s.mean_topology(), k_max=s.k_max, t_max=s.t_max).total
+                return allocate(s.mean_topology(), k_max=s.k_max, t_max=s.t_max).total
             except (InsufficientResourcesError, UnstableTopologyError):
-                optimal = None
+                return None
+
+        optimals = map_distinct(self.scenarios, optimal_total)
+        out = []
+        for bi, (s, optimal) in enumerate(zip(self.scenarios, optimals)):
+            n = s.graph.n
             offered = float(res.offered[bi, :n].sum())
             dropped = float(res.dropped[bi, :n].sum())
             decs = self.decisions[bi]

@@ -1445,7 +1445,6 @@ def make_decide_jax(
 
         return jax.jit(decide)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis, n_shards = _mesh_axis(mesh)
@@ -1471,12 +1470,12 @@ def make_decide_jax(
             ok=lane, lam=row, mu=row, drop=row, lam0=lane, k=row,
             code=lane, k_next=row, et_cur=lane, et_target=lane, applied=lane,
         )
-        sharded_c = shard_map(
+        sharded_c = jax.shard_map(
             core_c,
             mesh=mesh,
             in_specs=(st_specs, row, row, row, lane, row, cache_specs),
             out_specs=((lane, row, lane, lane, lane), lane, cache_specs),
-            check_rep=False,
+            check_vma=False,
         )
 
         def decide_padded(lam_hat, mu_hat, drop_hat, lam0_hat, k_current,
@@ -1512,12 +1511,12 @@ def make_decide_jax(
         )
         return decide_compact
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         core,
         mesh=mesh,
         in_specs=(st_specs, row, row, row, lane, row),
         out_specs=(lane, row, lane, lane, lane),
-        check_rep=False,
+        check_vma=False,
     )
 
     def decide(lam_hat, mu_hat, drop_hat, lam0_hat, k_current):
@@ -1720,38 +1719,59 @@ def make_fused_loop(
     window = window_step_fn(interpret=interpret, force_kernel=force_kernel)
     # Every [B, ...]-leading array rides in one of two dicts so the mesh
     # path can pass them as explicit sharded operands (one P(axis, ...)
-    # rule per leaf) instead of full-size replicated closure constants.
-    st = {k_: jnp.asarray(v) for k_, v in _decide_statics(static, params).items()}
-    sim = {
-        "mu": jnp.asarray(arrays.mu),  # reference-class priors
-        "group": jnp.asarray(arrays.group),
-        "alpha": jnp.asarray(arrays.alpha),
-        "cap_queue": jnp.asarray(arrays.cap_queue),
-        "routing": jnp.asarray(arrays.routing),
-        "speed": jnp.asarray(static.speed),
-        "t_max": jnp.asarray(np.nan_to_num(params.t_max, nan=np.inf)),
+    # rule per leaf).  They are arguments of the compiled run, never
+    # closure constants, which XLA would embed in the program: at fleet
+    # extent the arrivals alone are hundreds of MB.
+    t_max_np = np.nan_to_num(params.t_max, nan=np.inf)
+    st_np = _decide_statics(static, params)
+    sim_np = {
+        "mu": arrays.mu,  # reference-class priors
+        "group": arrays.group,
+        "alpha": arrays.alpha,
+        "cap_queue": arrays.cap_queue,
+        "routing": arrays.routing,
+        "speed": static.speed,
+        "t_max": t_max_np,
         # §17 Allen-Cunneen inputs for the stationary-wait term of the
         # window measurement (ones = the M/M/k prior when unset).
-        "ca2": jnp.asarray(
-            np.ones((arrays.batch, arrays.n)) if arrays.ca2 is None
-            else arrays.ca2
-        ),
-        "cs2": jnp.asarray(
-            np.ones((arrays.batch, arrays.n)) if arrays.cs2 is None
-            else arrays.cs2
-        ),
+        "ca2": np.ones((arrays.batch, arrays.n)) if arrays.ca2 is None else arrays.ca2,
+        "cs2": np.ones((arrays.batch, arrays.n)) if arrays.cs2 is None else arrays.cs2,
     }
     # Pre-sliced per-tick arrival chunks + warmup masks.
-    ext_r = jnp.asarray(
-        arrays.ext[: n_ticks * steps_per_tick].reshape(
-            n_ticks, steps_per_tick, b, n
-        )
+    ext_np = arrays.ext[: n_ticks * steps_per_tick].reshape(
+        n_ticks, steps_per_tick, b, n
     )
-    warm_r = jnp.asarray(
+    warm_np = (
         (np.arange(n_ticks * steps_per_tick) >= arrays.warmup_steps)
         .astype(np.float64)
         .reshape(n_ticks, steps_per_tick)
     )
+    if mesh is None:
+        place = jnp.asarray
+    else:
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        def _lane_spec(v):
+            nd = getattr(v, "ndim", 0)
+            return P(axis, *((None,) * (nd - 1))) if nd >= 1 else P()
+
+        st_specs = {k_: _lane_spec(v) for k_, v in st_np.items()}
+        sim_specs = {k_: _lane_spec(v) for k_, v in sim_np.items()}
+        data_specs = (P(None, None, axis, None), P(None, None))
+
+        def place(x, spec=None):
+            # Each device receives only its own lane shard.
+            spec = _lane_spec(x) if spec is None else spec
+            return jax.device_put(x, NamedSharding(mesh, spec))
+
+    st = {k_: place(v) for k_, v in st_np.items()}
+    sim = {k_: place(v) for k_, v in sim_np.items()}
+    if mesh is None:
+        ext_r, warm_r = place(ext_np), place(warm_np)
+    else:
+        ext_r, warm_r = place(ext_np, data_specs[0]), place(warm_np, data_specs[1])
+    data = (st, sim, ext_r, warm_r)
     # A window counts as warm when it *starts* past the warmup boundary,
     # compared in seconds like the twin runner (t0 >= warmup), not in
     # rounded steps — the run-accumulator gating above stays step-based
@@ -1763,7 +1783,7 @@ def make_fused_loop(
         (np.arange(n_ticks) * steps_per_tick * dt >= warmup_s).astype(np.float64)
     )
     span = steps_per_tick * dt
-    t_max_real = sim["t_max"][:b_real]
+    t_max_real = jnp.asarray(t_max_np[:b_real])
 
     if proactive is not None:
         from ..forecast.mpc import forecast_init_state, forecast_step, mpc_plan
@@ -2022,32 +2042,24 @@ def make_fused_loop(
                 [k0, np.zeros((b - k0.shape[0], n), dtype=k0.dtype)]
             )
         # Each leaf gets its OWN buffer: the run step donates the whole
-        # state, and XLA rejects the same buffer donated twice.
-        def zeros2():
-            return jnp.zeros((b, n))
+        # state, and XLA rejects the same buffer donated twice.  Under a
+        # mesh each leaf is placed lane-sharded, like the loop's data.
+        def zeros(*shape):
+            return place(np.zeros(shape))
 
-        acc0 = (zeros2(), zeros2(), zeros2(), jnp.zeros(b), jnp.zeros(b),
-                zeros2(), zeros2())
+        acc0 = (zeros(b, n), zeros(b, n), zeros(b, n), zeros(b), zeros(b),
+                zeros(b, n), zeros(b, n))
         fstate = ()
         if proactive is not None:
-            fstate = tuple(jnp.array(x) for x in fstate0)  # copies: see above
+            fstate = tuple(place(np.asarray(x)) for x in fstate0)  # copies
         return ControllerState(
-            q=zeros2(), served_prev=zeros2(),
-            k=jnp.asarray(k0, dtype=jnp.int32),
-            acc=acc0, tick=jnp.asarray(0, dtype=jnp.int32),
+            q=zeros(b, n), served_prev=zeros(b, n),
+            k=place(k0.astype(np.int32)),
+            acc=acc0, tick=place(np.asarray(0, dtype=np.int32)),
             fstate=fstate,
         )
 
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
-
-        def _lane_spec(v):
-            nd = getattr(v, "ndim", 0)
-            return P(axis, *((None,) * (nd - 1))) if nd >= 1 else P()
-
-        st_specs = {k_: _lane_spec(v) for k_, v in st.items()}
-        sim_specs = {k_: _lane_spec(v) for k_, v in sim.items()}
         state_specs = jax.tree.map(
             _lane_spec, init_fn(np.zeros((b_real, n), dtype=np.int64))
         )
@@ -2057,29 +2069,23 @@ def make_fused_loop(
             ys_specs = ys_specs + (ys_lane, ys_lane)
         if compact_cfg is not None:
             ys_specs = ys_specs + (ys_lane,)
-        data_specs = (P(None, None, axis, None), P(None, None))
 
     def build(ticks: int):
-        if mesh is None:
-            def stepped(state):
-                return chunk(ticks, st, sim, ext_r, warm_r, state)
-        else:
-            sharded = shard_map(
-                lambda st_, sim_, ext_, warm_, state_: chunk(
-                    ticks, st_, sim_, ext_, warm_, state_
-                ),
+        def stepped(data_, state):
+            return chunk(ticks, *data_, state)
+
+        if mesh is not None:
+            stepped = jax.shard_map(
+                stepped,
                 mesh=mesh,
-                in_specs=(st_specs, sim_specs) + data_specs + (state_specs,),
+                in_specs=((st_specs, sim_specs) + data_specs, state_specs),
                 out_specs=(state_specs, ys_specs),
-                check_rep=False,
+                check_vma=False,
             )
 
-            def stepped(state):
-                return sharded(st, sim, ext_r, warm_r, state)
-
-        def run(state):
+        def run(data_, state):
             tick0 = state.tick
-            new_state, ys = stepped(state)
+            new_state, ys = stepped(data_, state)
             per_tick = tuple(y[:, :b_real] for y in ys)
             codes, k_hist, sojourns, et_cur, et_target, applied = per_tick[:6]
             # Warm flags + miss counting stay OUTSIDE shard_map: they are
@@ -2106,6 +2112,7 @@ def make_fused_loop(
                 out["repriced"] = per_tick[-1]
             return new_state, out
 
-        return jax.jit(run, donate_argnums=0)
+        jitted = jax.jit(run, donate_argnums=1)
+        return lambda state: jitted(data, state)
 
     return FusedLoop(n_ticks, init_fn, build), n_ticks

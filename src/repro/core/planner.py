@@ -340,8 +340,9 @@ def _merged_topr(
         take = topr_ops.gain_topr(jnp.asarray(cand[None]), budget_arr)[0]
         return np.asarray(take, dtype=np.int64)
 
+    import jax
     from jax import lax
-    from jax.experimental.shard_map import shard_map
+    from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
     if len(mesh.axis_names) != 1:
@@ -358,8 +359,8 @@ def _merged_topr(
         i0 = lax.axis_index(axis) * local_rows.shape[0]
         return lax.dynamic_slice_in_dim(take_all, i0, local_rows.shape[0])
 
-    take = shard_map(
-        solve, mesh=mesh, in_specs=P(axis, None), out_specs=P(axis),
-        check_rep=False,
-    )(jnp.asarray(cand))
+    rows = P(axis, None)
+    take = jax.shard_map(
+        solve, mesh=mesh, in_specs=rows, out_specs=P(axis), check_vma=False,
+    )(jax.device_put(cand, NamedSharding(mesh, rows)))
     return np.asarray(take[:r], dtype=np.int64)

@@ -42,6 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..gain_topr.kernel import row_total
+
 __all__ = ["batch_decide_pallas"]
 
 _LANE = 128
@@ -73,7 +75,9 @@ def _decide_fused_kernel(
     alpha = alpha_ref[...]
     act = act_ref[...] > 0.0
     kcur = kcur_ref[...]
-    kmax = kmax_ref[0, 0].astype(jnp.float32)
+    # Per-scenario scalars ride as lane-replicated (1, Np) rows (see
+    # kernels/gain_topr ``row_total``).
+    kmax = jnp.broadcast_to(kmax_ref[...], lam.shape).astype(jnp.float32)
 
     inf = jnp.float32(jnp.inf)
     one = jnp.float32(1.0)  # typed: weak-float where() would promote to f64
@@ -113,8 +117,9 @@ def _decide_fused_kernel(
 
     T = t_scr[...]
     G = g_scr[...]
-    kio_t = jax.lax.broadcasted_iota(jnp.float32, T.shape, 0)
-    kio_g = jax.lax.broadcasted_iota(jnp.float32, G.shape, 0)
+    # Mosaic's iota is integer-only; the row indices are exact in f32.
+    kio_t = jax.lax.broadcasted_iota(jnp.int32, T.shape, 0).astype(jnp.float32)
+    kio_g = jax.lax.broadcasted_iota(jnp.int32, G.shape, 0).astype(jnp.float32)
 
     # Minimal feasible allocation: first finite table row per lane.
     fin = jnp.isfinite(T) & (kio_t <= k_hi)
@@ -123,7 +128,7 @@ def _decide_fused_kernel(
     )
     has_f = first <= k_hi
     kst = jnp.where(act, jnp.where(has_f, first, jnp.float32(k_hi + 1)), 0.0)
-    floor_total = jnp.sum(kst)
+    floor_total = row_total(kst)
     bud = jnp.maximum(kmax - floor_total, 0.0)
 
     # Program 4: masked top-R over the raw gain table.  The window mask
@@ -133,30 +138,34 @@ def _decide_fused_kernel(
         & act & jnp.isfinite(G)
     )
     pos = win & (G > 0.0)
-    pos_row = jnp.sum(jnp.where(pos, one, zero), axis=0, keepdims=True)
-    total_pos = jnp.sum(pos_row)
-    use_all = total_pos <= bud
+
+    def count_row(mask):  # (rows, Np) mask -> (1, Np) per-operator count
+        return jnp.sum(jnp.where(mask, one, zero), axis=0, keepdims=True)
+
+    pos_row = count_row(pos)
+    use_all = row_total(pos_row) <= bud
 
     def bisect(_, lohi):
         lo, hi = lohi
-        mid = lo + (hi - lo) // 2  # int32-overflow-safe midpoint
+        mid = lo + ((hi - lo) >> 1)  # overflow-safe midpoint (hi > lo)
         t = jax.lax.bitcast_convert_type(mid, jnp.float32)
-        c = jnp.sum(jnp.where(pos & (G >= t), one, zero))
+        c = row_total(count_row(pos & (G >= t)))
         enough = c >= bud  # still >= budget entries at/above mid
         return jnp.where(enough, mid, lo), jnp.where(enough, hi, mid)
 
     # Invariant: count(>= bitcast(lo)) >= budget > count(>= bitcast(hi));
     # 31 halvings leave bitcast(lo) == the budget-th largest positive gain.
+    np_ = lam.shape[-1]
     lo, _hi = jax.lax.fori_loop(
-        0, 31, bisect, (jnp.int32(1), jnp.int32(0x7F800000))
+        0, 31, bisect,
+        (jnp.full((1, np_), 1, jnp.int32), jnp.full((1, np_), 0x7F800000, jnp.int32)),
     )
     thresh = jax.lax.bitcast_convert_type(lo, jnp.float32)
-    strict = jnp.sum(jnp.where(pos & (G > thresh), one, zero), axis=0, keepdims=True)
-    ties = jnp.sum(jnp.where(pos & (G == thresh), one, zero), axis=0, keepdims=True)
-    rem = bud - jnp.sum(strict)
-    np_ = ties.shape[-1]
-    row = jax.lax.broadcasted_iota(jnp.float32, (np_, np_), 0)
-    col = jax.lax.broadcasted_iota(jnp.float32, (np_, np_), 1)
+    strict = count_row(pos & (G > thresh))
+    ties = count_row(pos & (G == thresh))
+    rem = bud - row_total(strict)
+    row = jax.lax.broadcasted_iota(jnp.int32, (np_, np_), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (np_, np_), 1)
     lower = jnp.where(row < col, one, zero)  # strictly-lower mask
     before = jnp.dot(ties, lower, preferred_element_type=jnp.float32)
     extra = jnp.clip(jnp.minimum(ties, rem - before), zero, None)
@@ -211,30 +220,29 @@ def batch_decide_pallas(
 
     def lane(x, fill=0.0):
         x = jnp.asarray(x, dtype=jnp.float32)
-        return jnp.pad(x, ((0, 0), (0, npad - n)), constant_values=fill)
+        x = jnp.pad(x, ((0, 0), (0, npad - n)), constant_values=fill)
+        return x.reshape(b, 1, npad)
 
+    # Per-scenario operands carry a unit axis and the block squeezes the
+    # leading one, so each block's last two dims equal the array's (the
+    # TPU (8, 128) tiling rule).
     args = (
         lane(lam), lane(mu_eff), lane(group), lane(alpha), lane(active),
         lane(k_cur),
-        jnp.asarray(k_max, dtype=jnp.int32).reshape(b, 1),
+        jnp.asarray(k_max, dtype=jnp.int32).reshape(b, 1, 1),
     )
-    row_spec = pl.BlockSpec((1, npad), lambda i: (i, 0))
+    row_spec = pl.BlockSpec((None, 1, npad), lambda i: (i, 0, 0))
     out = pl.pallas_call(
         functools.partial(_decide_fused_kernel, k_hi=k_hi, j_cap=jc),
         grid=(b,),
-        in_specs=[row_spec] * 6 + [pl.BlockSpec((1, 1), lambda i: (i, 0))],
+        in_specs=[row_spec] * 6 + [pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0))],
         out_specs=[row_spec] * 4,
-        out_shape=[jax.ShapeDtypeStruct((b, npad), jnp.float32)] * 4,
+        out_shape=[jax.ShapeDtypeStruct((b, 1, npad), jnp.float32)] * 4,
         scratch_shapes=[
             pltpu.VMEM((rows_t, npad), jnp.float32),
             pltpu.VMEM((rows_g, npad), jnp.float32),
         ],
         interpret=interpret,
     )(*args)
-    k4f, kstf, tcurf, t4f = out
-    return (
-        k4f[:, :n].astype(jnp.int32),
-        kstf[:, :n].astype(jnp.int32),
-        tcurf[:, :n],
-        t4f[:, :n],
-    )
+    k4f, kstf, tcurf, t4f = (o[:, 0, :n] for o in out)
+    return k4f.astype(jnp.int32), kstf.astype(jnp.int32), tcurf, t4f

@@ -4,6 +4,6 @@ NOTE: do NOT import .dryrun here — it sets XLA_FLAGS at import time and
 must only ever be imported as the program entry point.
 """
 
-from .mesh import HW, make_local_mesh, make_production_mesh
+from .mesh import HW, make_local_mesh, make_mesh, make_production_mesh
 
-__all__ = ["HW", "make_local_mesh", "make_production_mesh"]
+__all__ = ["HW", "make_local_mesh", "make_mesh", "make_production_mesh"]

@@ -18,11 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from .common import ModelConfig, current_mesh, shard
 
 __all__ = ["swiglu", "moe_layer", "moe_layer_ep", "router_top_k"]
@@ -262,5 +257,5 @@ def moe_layer_ep(
         return out.reshape(b_loc, s, d), aux
 
     out_specs = (P(bd, None, None), P())
-    fn = _shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     return fn(*args)

@@ -202,10 +202,13 @@ class StreamEngine:
         # block: wait for space, polling so engine stop / deadline unblocks.
         poll = self.overload_policy.block_poll
         while not self._stop.is_set():
-            if deadline is not None and time.perf_counter() >= deadline:
-                break
+            wait = poll
+            if deadline is not None:
+                wait = min(poll, deadline - time.perf_counter())
+                if wait <= 0:
+                    break
             try:
-                q.put(tup, timeout=poll)
+                q.put(tup, timeout=wait)
                 return True
             except queue.Full:
                 continue

@@ -17,12 +17,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.models.common import ModelConfig, axis_rules
 from repro.models.ffn import moe_layer, moe_layer_ep
 
 
 def main() -> None:
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = ModelConfig(
         arch="ep-test", family="moe", n_layers=1, d_model=32, n_heads=4,
         n_kv_heads=2, d_ff=64, vocab=64, n_experts=8, top_k=2,

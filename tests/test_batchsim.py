@@ -124,7 +124,7 @@ def test_batchsim_numpy_twin_matches_jit_x64():
     scens = scenario_matrix(5, seed=7, horizon=15.0, warmup=2.0)
     ks = [s.plan_k0() for s in scens]
     _, _, rn = run_batch(scens, ks, backend="numpy")
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         _, _, rj = run_batch(scens, ks, backend="jax")
     for name in ("offered", "served", "dropped", "q_final", "q_mean", "ext_admitted"):
         np.testing.assert_allclose(
@@ -136,7 +136,7 @@ def test_batchsim_jit_pallas_interpret_agrees():
     scens = scenario_matrix(3, seed=9, horizon=10.0, warmup=1.0)
     ks = [s.plan_k0() for s in scens]
     _, _, rn = run_batch(scens, ks, backend="numpy")
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         _, _, rk = run_batch(scens, ks, backend="jax", force_kernel=True, interpret=True)
     # float32 kernel inside a float64 scan: loose elementwise agreement
     np.testing.assert_allclose(rk.offered, rn.offered, rtol=1e-4, atol=1e-2)

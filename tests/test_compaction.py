@@ -107,7 +107,7 @@ def test_bucket_ladder_shape():
 def test_compacted_decide_bit_identity_swept_trigger_fractions():
     import jax
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         scens = _scens(32)
         r = ScenarioRunner(scens, tick_interval=5.0, backend="jax")
         st, pr = r.static, r._params()
@@ -136,7 +136,7 @@ def test_compacted_decide_bit_identity_swept_trigger_fractions():
 def test_compacted_decide_k_and_custom_ladder_and_nan():
     import jax
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         scens = _scens(8)
         r = ScenarioRunner(scens, tick_interval=5.0, backend="jax")
         st, pr = r.static, r._params()
@@ -194,7 +194,7 @@ def _assert_loop_match(ref, got, extra_exact=()):
 def _fused_out(scens, compact, **kw):
     import jax
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         r = ScenarioRunner(scens, tick_interval=5.0, backend="jax",
                            compact=compact, **kw)
         assert r.fused

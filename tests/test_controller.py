@@ -205,7 +205,7 @@ def test_fused_loop_matches_twin_under_x64():
         s.with_(negotiated=False)
         for s in scenario_matrix(4, seed=11, horizon=20.0, warmup=5.0, dt=0.05)
     ]
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         twin = ScenarioRunner(scens, tick_interval=5.0, backend="numpy")
         r_twin = twin.run()
         fused = ScenarioRunner(scens, tick_interval=5.0, backend="jax")
@@ -225,7 +225,7 @@ def test_fused_warm_window_rule_matches_twin():
         s.with_(negotiated=False, warmup=5.3, dt=0.25, horizon=20.0)
         for s in scenario_matrix(3, seed=6, horizon=20.0, warmup=5.3, dt=0.25)
     ]
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         twin = ScenarioRunner(scens, tick_interval=5.0, backend="numpy")
         r_twin = twin.run()
         fused = ScenarioRunner(scens, tick_interval=5.0, backend="jax")

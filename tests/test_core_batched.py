@@ -158,7 +158,7 @@ def test_jax_table_agrees_f32():
 def test_jax_table_agrees_1e9_under_x64():
     top = vld_top()
     T = sojourn_table(top, 40)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         Tj = np.asarray(
             sojourn_table_jax(
                 jnp.asarray(top.arrival_rates), jnp.asarray([2.0, 5.0, 50.0]), k_hi=40

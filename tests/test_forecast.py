@@ -151,7 +151,7 @@ def test_forecast_rates_twin_vs_jit(kind):
     hist = rng.uniform(1.0, 25.0, (5, 12, 4))
     pp = PredictorParams(kind=kind, alpha=0.55, beta=0.35,
                          season=6 if kind == "seasonal" else 0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         want = forecast_rates(hist, 4, pp, xp=np)
         got = jax.jit(lambda h: forecast_rates(h, 4, pp, xp=jnp))(
             jnp.asarray(hist))
@@ -176,7 +176,7 @@ def test_mpc_plan_twin_vs_jit():
         k_max=np.full(b, 48, dtype=np.int64),
         span=10.0, cfg=MPCConfig(horizon=hzn, window=12), k_hi=k_hi,
     )
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         want = mpc_plan(lam_pred, q0, k_cur, xp=np, **kw)
         got = jax.jit(
             lambda lp, q, k: mpc_plan(lp, q, k, xp=jnp,
@@ -235,7 +235,7 @@ def test_reactive_runner_has_no_proactive_actions_but_has_trajectory():
 def test_proactive_fused_matches_twin_under_x64():
     scens = [_ramp_scenario(negotiated=False)]
     cfg = _cfg()
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         twin = ScenarioRunner(scens, tick_interval=10.0, backend="numpy",
                               proactive=cfg)
         r_twin = twin.run()[0]

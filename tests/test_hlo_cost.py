@@ -49,8 +49,6 @@ def test_scan_multiplies_by_trip_count():
     assert cost.while_count >= 1
     # the builtin cost_analysis undercounts (this is why hlo_cost exists)
     builtin = jax.jit(f).lower(w, x).compile().cost_analysis()
-    if isinstance(builtin, (list, tuple)):  # jax <= 0.4.x: one dict per device
-        builtin = builtin[0]
     assert builtin["flops"] < expect / 2
 
 

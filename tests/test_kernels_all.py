@@ -254,7 +254,7 @@ def test_decide_fused_oracle_matches_numpy_twin_x64(seeds, k_hi):
     """jnp oracle == float64 numpy twin bit-for-bit under x64, across the
     zoo and up to K=1024."""
     case = _zoo_decide_case(seeds)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         got = _decide(ddref.batch_decide, case, k_hi)
     want = _decide(ddref.batch_decide_np, case, k_hi)
     for name, g, w in zip(("k4", "k_start", "t_cur", "t4"), got, want):
@@ -276,7 +276,7 @@ def test_decide_fused_matches_two_pass_decide_bitwise(x64):
         s.with_(negotiated=False)
         for s in scenario_matrix(4, seed=17, horizon=20.0, warmup=5.0, dt=0.05)
     ]
-    with jax.experimental.enable_x64() if x64 else contextlib.nullcontext():
+    with jax.enable_x64(True) if x64 else contextlib.nullcontext():
         r = ScenarioRunner(scens, tick_interval=5.0, backend="jax")
         b, n = len(scens), r.static.n
         rng = np.random.default_rng(5)
@@ -327,7 +327,7 @@ def test_decide_fused_jcap_truncation_is_exact():
     identical to the full-window selection — bitwise, not approximately."""
     case = _zoo_decide_case((11, 12, 13))
     k_max = case[-1]
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         full = _decide(ddref.batch_decide, case, 128, j_cap=None)
         capped = _decide(ddref.batch_decide, case, 128, j_cap=int(k_max.max()))
     for name, a, b in zip(("k4", "k_start", "t_cur", "t4"), full, capped):
