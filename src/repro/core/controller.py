@@ -59,6 +59,7 @@ from .allocator import (
     min_processors,
     min_processors_table,
 )
+from . import stages
 from .jackson import OperatorSpec, Topology, UnstableTopologyError
 from .measurer import MeasurementBatch
 from .rebalance import RebalanceCostModel, RebalancePlan
@@ -418,49 +419,52 @@ def _make_compact_decide(core, b: int, ladder: tuple[int, ...]):
         return (a != c) & ~(jnp.isnan(a) & jnp.isnan(c))
 
     def decide(st, lam_hat, mu_hat, drop_hat, lam0_hat, k_current, cache):
-        k_in = k_current.astype(jnp.int32)
-        # --- trigger scan: O(B*N), no table/solve/top-R work ----------- #
-        mu_eff = mu_hat * st["speed"]
-        k_floor = jnp.maximum(k_in, 1).astype(lam_hat.dtype)
-        eff = 1.0 / (1.0 + st["alpha"] * (k_floor - 1.0))
-        capacity = jnp.where(
-            st["group"], mu_eff * k_floor * eff, mu_eff * k_floor
-        )
-        valid = jnp.isfinite(lam_hat) & jnp.isfinite(mu_eff) & (mu_eff > 0)
-        drops = jnp.nan_to_num(drop_hat, nan=0.0)
-        hot = (
-            valid & st["active"] & (
-                (lam_hat >= capacity * (1.0 - 1e-9))
-                | (drops > DROP_TRIGGER_FRACTION * capacity)
+        # The whole body is the compact stage; the core priced inside a
+        # ladder branch keeps its own (innermost) stages.
+        with stages.scope("compact"):
+            k_in = k_current.astype(jnp.int32)
+            # --- trigger scan: O(B*N), no table/solve/top-R work ----------- #
+            mu_eff = mu_hat * st["speed"]
+            k_floor = jnp.maximum(k_in, 1).astype(lam_hat.dtype)
+            eff = 1.0 / (1.0 + st["alpha"] * (k_floor - 1.0))
+            capacity = jnp.where(
+                st["group"], mu_eff * k_floor * eff, mu_eff * k_floor
             )
-        ).any(axis=-1)
-        changed = (
-            _neq(lam_hat, cache.lam).any(axis=-1)
-            | _neq(mu_hat, cache.mu).any(axis=-1)
-            | _neq(drop_hat, cache.drop).any(axis=-1)
-            | _neq(lam0_hat, cache.lam0)
-            | (k_in != cache.k).any(axis=-1)
-        )
-        repriced = ~cache.ok | changed | hot
-
-        # --- compacted decide + cached-row fast path ------------------- #
-        def price(g):
-            st_g = {key: val[g] for key, val in st.items()}
-            return core(
-                st_g, lam_hat[g], mu_hat[g], drop_hat[g], lam0_hat[g], k_in[g]
+            valid = jnp.isfinite(lam_hat) & jnp.isfinite(mu_eff) & (mu_eff > 0)
+            drops = jnp.nan_to_num(drop_hat, nan=0.0)
+            hot = (
+                valid & st["active"] & (
+                    (lam_hat >= capacity * (1.0 - 1e-9))
+                    | (drops > DROP_TRIGGER_FRACTION * capacity)
+                )
+            ).any(axis=-1)
+            changed = (
+                _neq(lam_hat, cache.lam).any(axis=-1)
+                | _neq(mu_hat, cache.mu).any(axis=-1)
+                | _neq(drop_hat, cache.drop).any(axis=-1)
+                | _neq(lam0_hat, cache.lam0)
+                | (k_in != cache.k).any(axis=-1)
             )
+            repriced = ~cache.ok | changed | hot
 
-        code, k_next, et_cur, et_target, applied = _bucketed(
-            ladder, b, repriced, price,
-            (cache.code, cache.k_next, cache.et_cur, cache.et_target,
-             cache.applied),
-        )
-        new_cache = DecideCache(
-            ok=jnp.ones_like(cache.ok),
-            lam=lam_hat, mu=mu_hat, drop=drop_hat, lam0=lam0_hat, k=k_in,
-            code=code, k_next=k_next, et_cur=et_cur, et_target=et_target,
-            applied=applied,
-        )
+            # --- compacted decide + cached-row fast path ------------------- #
+            def price(g):
+                st_g = {key: val[g] for key, val in st.items()}
+                return core(
+                    st_g, lam_hat[g], mu_hat[g], drop_hat[g], lam0_hat[g], k_in[g]
+                )
+
+            code, k_next, et_cur, et_target, applied = _bucketed(
+                ladder, b, repriced, price,
+                (cache.code, cache.k_next, cache.et_cur, cache.et_target,
+                 cache.applied),
+            )
+            new_cache = DecideCache(
+                ok=jnp.ones_like(cache.ok),
+                lam=lam_hat, mu=mu_hat, drop=drop_hat, lam0=lam0_hat, k=k_in,
+                code=code, k_next=k_next, et_cur=et_cur, et_target=et_target,
+                applied=applied,
+            )
         return (code, k_next, et_cur, et_target, applied), repriced, new_cache
 
     return decide
@@ -1191,49 +1195,51 @@ def _make_decide_core(
         horizon = st["horizon"]
         b = lam_hat.shape[0]
         dtype = lam_hat.dtype
-        mu_eff = mu_hat * speed
-        k_cur = k_current.astype(jnp.int32)
         # --- overload trigger + capped propagation (§11) --------------- #
-        k_floor = jnp.maximum(k_cur, 1).astype(dtype)
-        eff = 1.0 / (1.0 + alpha * (k_floor - 1.0))
-        capacity = jnp.where(group, mu_eff * k_floor * eff, mu_eff * k_floor)
-        valid = jnp.isfinite(lam_hat) & jnp.isfinite(mu_eff) & (mu_eff > 0)
-        drops = jnp.nan_to_num(drop_hat, nan=0.0)
-        overloaded = valid & active & (
-            (lam_hat >= capacity * (1.0 - 1e-9))
-            | (drops > DROP_TRIGGER_FRACTION * capacity)
-        )
-        hot = overloaded.any(axis=-1)
+        with stages.scope("trigger"):
+            mu_eff = mu_hat * speed
+            k_cur = k_current.astype(jnp.int32)
+            k_floor = jnp.maximum(k_cur, 1).astype(dtype)
+            eff = 1.0 / (1.0 + alpha * (k_floor - 1.0))
+            capacity = jnp.where(group, mu_eff * k_floor * eff, mu_eff * k_floor)
+            valid = jnp.isfinite(lam_hat) & jnp.isfinite(mu_eff) & (mu_eff > 0)
+            drops = jnp.nan_to_num(drop_hat, nan=0.0)
+            overloaded = valid & active & (
+                (lam_hat >= capacity * (1.0 - 1e-9))
+                | (drops > DROP_TRIGGER_FRACTION * capacity)
+            )
+            hot = overloaded.any(axis=-1)
 
-        def _prop(_, out_c):
-            return overloaded | (adj & out_c[:, :, None]).any(axis=1)
+            def _prop(_, out_c):
+                return overloaded | (adj & out_c[:, :, None]).any(axis=1)
 
-        out_c = jax.lax.fori_loop(0, n, _prop, overloaded)
-        capped = (adj & out_c[:, :, None]).any(axis=1) & active
+            out_c = jax.lax.fori_loop(0, n, _prop, overloaded)
+            capped = (adj & out_c[:, :, None]).any(axis=1) & active
 
         # --- offered-load clamping (topology_from) ---------------------- #
-        lam_src = jnp.where(src_mask & jnp.isfinite(lam_hat), lam_hat, 0.0)
-        total_src = jnp.maximum(lam_src.sum(axis=-1), 1e-12)
-        lam0_cold = jnp.where(
-            jnp.isfinite(lam0_hat)[:, None],
-            lam0_hat[:, None] * (lam_src / total_src[:, None]),
-            lam_src,
-        )
-        lam0 = jnp.where(src_mask, jnp.where(hot[:, None], lam_src, lam0_cold), 0.0)
-        colsum = routing0.sum(axis=1)
-        inflow = jnp.einsum("bij,bi->bj", routing0, jnp.where(active, lam_hat, 0.0))
-        rescale = jnp.where(
-            (colsum > 0) & ~capped & (inflow > 1e-12)
-            & jnp.isfinite(lam_hat) & (lam_hat > 0),
-            lam_hat / jnp.maximum(inflow, 1e-300),
-            1.0,
-        )
-        routing = routing0.astype(dtype) * rescale[:, None, :]
-        lam = solve_traffic_batch_jax(lam0, routing)
-        lam = jnp.where(active, lam, 0.0)
-        solve_bad = (~jnp.isfinite(lam) | (lam < 0)).any(axis=-1)
-        lam = jnp.where(jnp.isfinite(lam) & (lam >= 0), lam, 0.0)
-        lam0_total = lam0.sum(axis=-1)
+        with stages.scope("solve"):
+            lam_src = jnp.where(src_mask & jnp.isfinite(lam_hat), lam_hat, 0.0)
+            total_src = jnp.maximum(lam_src.sum(axis=-1), 1e-12)
+            lam0_cold = jnp.where(
+                jnp.isfinite(lam0_hat)[:, None],
+                lam0_hat[:, None] * (lam_src / total_src[:, None]),
+                lam_src,
+            )
+            lam0 = jnp.where(src_mask, jnp.where(hot[:, None], lam_src, lam0_cold), 0.0)
+            colsum = routing0.sum(axis=1)
+            inflow = jnp.einsum("bij,bi->bj", routing0, jnp.where(active, lam_hat, 0.0))
+            rescale = jnp.where(
+                (colsum > 0) & ~capped & (inflow > 1e-12)
+                & jnp.isfinite(lam_hat) & (lam_hat > 0),
+                lam_hat / jnp.maximum(inflow, 1e-300),
+                1.0,
+            )
+            routing = routing0.astype(dtype) * rescale[:, None, :]
+            lam = solve_traffic_batch_jax(lam0, routing)
+            lam = jnp.where(active, lam, 0.0)
+            solve_bad = (~jnp.isfinite(lam) | (lam < 0)).any(axis=-1)
+            lam = jnp.where(jnp.isfinite(lam) & (lam >= 0), lam, 0.0)
+            lam0_total = lam0.sum(axis=-1)
 
         def _et_of(per_op):
             # Shared pricing tail: both decide paths produce raw per-op
@@ -1253,35 +1259,38 @@ def _make_decide_core(
             infeasible = solve_bad | (floor_total > k_max)
         else:
             # --- one table pass: E[T_i](k) and Algorithm-1 gains -------- #
-            T = sojourn_table_jax(
-                lam.reshape(-1), mu_eff.reshape(-1), k_hi=k_hi,
-                group=group.reshape(-1), alpha=alpha.reshape(-1),
-                min_k=jnp.ones(b * n, dtype=jnp.int32),
-                interpret=interpret, force_kernel=force_kernel,
-            ).reshape(b, n, k_hi + 1)
-            G = lam[..., None] * (T[..., :-1] - T[..., 1:])
-            G = jnp.where(jnp.isfinite(T[..., :-1]), G, jnp.inf)
+            with stages.scope("table"):
+                T = sojourn_table_jax(
+                    lam.reshape(-1), mu_eff.reshape(-1), k_hi=k_hi,
+                    group=group.reshape(-1), alpha=alpha.reshape(-1),
+                    min_k=jnp.ones(b * n, dtype=jnp.int32),
+                    interpret=interpret, force_kernel=force_kernel,
+                ).reshape(b, n, k_hi + 1)
+                G = lam[..., None] * (T[..., :-1] - T[..., 1:])
+                G = jnp.where(jnp.isfinite(T[..., :-1]), G, jnp.inf)
 
-            # Minimal feasible allocation = first finite table column.
-            finite = jnp.isfinite(T)
-            has_finite = finite.any(axis=-1)
-            first = jnp.argmax(finite, axis=-1).astype(jnp.int32)
-            k_start = jnp.where(active, jnp.where(has_finite, first, k_hi + 1), 0)
-            floor_total = k_start.sum(axis=-1)
-            infeasible = solve_bad | (floor_total > k_max)
+                # Minimal feasible allocation = first finite table column.
+                finite = jnp.isfinite(T)
+                has_finite = finite.any(axis=-1)
+                first = jnp.argmax(finite, axis=-1).astype(jnp.int32)
+                k_start = jnp.where(active, jnp.where(has_finite, first, k_hi + 1), 0)
+                floor_total = k_start.sum(axis=-1)
+                infeasible = solve_bad | (floor_total > k_max)
 
             # --- Program (4): masked top-R over the gain table ---------- #
-            budget = jnp.clip(k_max - floor_total, 0, None).astype(jnp.int32)
-            j = jnp.arange(k_hi, dtype=jnp.int32)
-            idx = k_start[..., None] + j[None, None, :]
-            cand = jnp.take_along_axis(G, jnp.clip(idx, 0, k_hi - 1), axis=-1)
-            cand = jnp.where(
-                (idx < k_hi) & active[..., None] & jnp.isfinite(cand), cand, 0.0
-            )
-            take = topr_ops.gain_topr(
-                cand, budget, interpret=interpret, force_kernel=force_kernel
-            )
-            k4 = k_start + take
+            with stages.scope("candidates"):
+                budget = jnp.clip(k_max - floor_total, 0, None).astype(jnp.int32)
+                j = jnp.arange(k_hi, dtype=jnp.int32)
+                idx = k_start[..., None] + j[None, None, :]
+                cand = jnp.take_along_axis(G, jnp.clip(idx, 0, k_hi - 1), axis=-1)
+                cand = jnp.where(
+                    (idx < k_hi) & active[..., None] & jnp.isfinite(cand), cand, 0.0
+                )
+            with stages.scope("topr"):
+                take = topr_ops.gain_topr(
+                    cand, budget, interpret=interpret, force_kernel=force_kernel
+                )
+                k4 = k_start + take
 
             def _gather(k_vec):
                 return jnp.take_along_axis(
@@ -1289,57 +1298,61 @@ def _make_decide_core(
                     axis=-1,
                 )[..., 0]
 
-            t_cur_op = _gather(k_cur)
-            t4_op = _gather(k4)
+            with stages.scope("price"):
+                t_cur_op = _gather(k_cur)
+                t4_op = _gather(k4)
 
-        et_cur = _et_of(t_cur_op)
-        et4 = _et_of(t4_op)
+        with stages.scope("price"):
+            et_cur = _et_of(t_cur_op)
+            et4 = _et_of(t4_op)
 
         # --- gates (vectorized improvement + cost/benefit) -------------- #
-        unchanged = jnp.where(active, k4 == k_cur, True).all(axis=-1)
-        improvement = jnp.where(
-            jnp.isfinite(et_cur) & (et_cur > 0),
-            (et_cur - et4) / et_cur,
-            jnp.inf,
-        )
-        visit = lam / jnp.maximum(lam0_total, 1e-300)[:, None]
-        cap_new = jnp.where(
-            active,
-            k4.astype(dtype) * mu_eff / jnp.maximum(visit, 1e-12),
-            jnp.inf,
-        ).min(axis=-1)
-        slack = jnp.maximum(cap_new - lam0_total, 1e-9)
-        drain = lam0_total * pause / slack
-        benefit = jnp.where(jnp.isfinite(et_cur), et_cur - et4, jnp.inf)
-        worthwhile = benefit * lam0_total * horizon > (
-            (pause + drain) * jnp.maximum(lam0_total, 1.0)
-        )
-        rebalance = (
-            ~unchanged
-            & (improvement >= min_improvement)
-            & (worthwhile | ~jnp.isfinite(et_cur))
-        )
+        with stages.scope("gates"):
+            unchanged = jnp.where(active, k4 == k_cur, True).all(axis=-1)
+            improvement = jnp.where(
+                jnp.isfinite(et_cur) & (et_cur > 0),
+                (et_cur - et4) / et_cur,
+                jnp.inf,
+            )
+            visit = lam / jnp.maximum(lam0_total, 1e-300)[:, None]
+            cap_new = jnp.where(
+                active,
+                k4.astype(dtype) * mu_eff / jnp.maximum(visit, 1e-12),
+                jnp.inf,
+            ).min(axis=-1)
+            slack = jnp.maximum(cap_new - lam0_total, 1e-9)
+            drain = lam0_total * pause / slack
+            benefit = jnp.where(jnp.isfinite(et_cur), et_cur - et4, jnp.inf)
+            worthwhile = benefit * lam0_total * horizon > (
+                (pause + drain) * jnp.maximum(lam0_total, 1.0)
+            )
+            rebalance = (
+                ~unchanged
+                & (improvement >= min_improvement)
+                & (worthwhile | ~jnp.isfinite(et_cur))
+            )
 
-        # --- action selection (precedence mirrors the twin) ------------- #
-        complete = (
-            jnp.where(active, jnp.isfinite(lam_hat) & jnp.isfinite(mu_hat), True)
-            .all(axis=-1)
-            & jnp.isfinite(lam0_hat)
-        )
-        feasible4 = ~infeasible
-        code = jnp.where(
-            rebalance, _CODE["rebalance"], _CODE["none"]
-        )
-        code = jnp.where(
-            infeasible & ~hot | (solve_bad & hot), _CODE["infeasible"], code
-        )
-        code = jnp.where(hot & ~solve_bad, _CODE["overloaded"], code)
-        code = jnp.where(~complete, _CODE["none"], code)
-        apply_mask = complete & ~solve_bad & feasible4 & (
-            (hot) | rebalance
-        )
-        k_next = jnp.where(apply_mask[:, None], k4, k_cur)
-        return code, k_next, et_cur, jnp.where(feasible4, et4, jnp.inf), apply_mask
+            # --- action selection (precedence mirrors the twin) ------------- #
+            complete = (
+                jnp.where(active, jnp.isfinite(lam_hat) & jnp.isfinite(mu_hat), True)
+                .all(axis=-1)
+                & jnp.isfinite(lam0_hat)
+            )
+            feasible4 = ~infeasible
+            code = jnp.where(
+                rebalance, _CODE["rebalance"], _CODE["none"]
+            )
+            code = jnp.where(
+                infeasible & ~hot | (solve_bad & hot), _CODE["infeasible"], code
+            )
+            code = jnp.where(hot & ~solve_bad, _CODE["overloaded"], code)
+            code = jnp.where(~complete, _CODE["none"], code)
+            apply_mask = complete & ~solve_bad & feasible4 & (
+                (hot) | rebalance
+            )
+            k_next = jnp.where(apply_mask[:, None], k4, k_cur)
+            et_target = jnp.where(feasible4, et4, jnp.inf)
+        return code, k_next, et_cur, et_target, apply_mask
 
     return decide
 
@@ -1423,11 +1436,11 @@ def make_decide_jax(
 
         if compact:
             core_c = _make_compact_decide(core, b, _resolve_ladder(compact, b))
-            jitted = jax.jit(
+            jitted = stages.recorded(jax.jit(
                 lambda lam, mu, drop, lam0, k, cache: core_c(
                     st, lam, mu, drop, lam0, k, cache
                 )
-            )
+            ))
 
             def decide_compact(lam_hat, mu_hat, drop_hat, lam0_hat, k_current,
                                cache):
@@ -1443,7 +1456,7 @@ def make_decide_jax(
         def decide(lam_hat, mu_hat, drop_hat, lam0_hat, k_current):
             return core(st, lam_hat, mu_hat, drop_hat, lam0_hat, k_current)
 
-        return jax.jit(decide)
+        return stages.recorded(jax.jit(decide))
 
     from jax.sharding import PartitionSpec as P
 
@@ -1499,7 +1512,7 @@ def make_decide_jax(
                 repriced = repriced[:b]
             return out, repriced, cache
 
-        jitted = jax.jit(decide_padded)
+        jitted = stages.recorded(jax.jit(decide_padded))
 
         def decide_compact(lam_hat, mu_hat, drop_hat, lam0_hat, k_current,
                            cache):
@@ -1534,7 +1547,7 @@ def make_decide_jax(
             out = tuple(o[:b] for o in out)
         return out
 
-    return jax.jit(decide)
+    return stages.recorded(jax.jit(decide))
 
 
 class ControllerState(NamedTuple):
@@ -1870,37 +1883,39 @@ def make_fused_loop(
                 q, served_prev, k, acc = carry
             ext_chunk = lax.dynamic_index_in_dim(ext_d, t_idx, 0, keepdims=False)
             warm_chunk = lax.dynamic_index_in_dim(warm_d, t_idx, 0, keepdims=False)
-            cap_serve_dt = capacity_of(sim_d, k) * dt
-            out = window(
-                q, served_prev, ext_chunk, warm_chunk, cap_serve_dt,
-                sim_d["cap_queue"], sim_d["routing"],
-            )
+            with stages.scope("window"):
+                cap_serve_dt = capacity_of(sim_d, k) * dt
+                out = window(
+                    q, served_prev, ext_chunk, warm_chunk, cap_serve_dt,
+                    sim_d["cap_queue"], sim_d["routing"],
+                )
             (q1, served_prev1, offered, served_sum, dropped, ext_adm, ext_off,
              q_int, q_max, w_offered, w_served, w_dropped, w_ext_adm, w_ext_off,
              w_q_int) = out
             # Window measurement (ungated): the §13 synthetic snapshot.
-            lam_hat = offered / span
-            drop_hat = dropped / span
-            admitted = jnp.maximum(lam_hat - drop_hat, 0.0)
-            q_mean = q_int / steps_per_tick
-            # §17 composed wait — the same helper (and op order) as the
-            # numpy twin's window measurement, so twin == jit holds on
-            # the measured-sojourn surface too.
-            wait = _composed_wait(
-                q_mean, admitted, dt, span, k, mu, group, alpha,
-                sim_d["speed"], sim_d["ca2"], sim_d["cs2"], xp=jnp,
-            )
-            cap = capacity_of(sim_d, k)
-            svc = jnp.where(
-                group,
-                jnp.where(cap > 0, 1.0 / cap, jnp.inf),
-                1.0 / mu_eff,
-            )
-            lam0 = jnp.maximum(ext_adm / span, 0.0)
-            contrib = jnp.where(admitted > 0, admitted * (wait + svc), 0.0)
-            sojourn = jnp.where(
-                lam0 > 0, contrib.sum(axis=-1) / jnp.maximum(lam0, 1e-300), jnp.nan
-            )
+            with stages.scope("measure"):
+                lam_hat = offered / span
+                drop_hat = dropped / span
+                admitted = jnp.maximum(lam_hat - drop_hat, 0.0)
+                q_mean = q_int / steps_per_tick
+                # §17 composed wait — the same helper (and op order) as the
+                # numpy twin's window measurement, so twin == jit holds on
+                # the measured-sojourn surface too.
+                wait = _composed_wait(
+                    q_mean, admitted, dt, span, k, mu, group, alpha,
+                    sim_d["speed"], sim_d["ca2"], sim_d["cs2"], xp=jnp,
+                )
+                cap = capacity_of(sim_d, k)
+                svc = jnp.where(
+                    group,
+                    jnp.where(cap > 0, 1.0 / cap, jnp.inf),
+                    1.0 / mu_eff,
+                )
+                lam0 = jnp.maximum(ext_adm / span, 0.0)
+                contrib = jnp.where(admitted > 0, admitted * (wait + svc), 0.0)
+                sojourn = jnp.where(
+                    lam0 > 0, contrib.sum(axis=-1) / jnp.maximum(lam0, 1e-300), jnp.nan
+                )
             if compact_cfg is not None:
                 dout, repriced, dcache = decide_c(
                     st_d, lam_hat, mu, drop_hat, lam0, k, dcache
@@ -1999,14 +2014,15 @@ def make_fused_loop(
                 applied = jnp.where(use, changed, applied)
                 et_cur = jnp.where(use, et_hold, et_cur)
                 et_target = jnp.where(use, et_plan, et_target)
-            new_acc = tuple(
-                a + w for a, w in zip(
-                    acc[:6],
-                    (w_offered, w_served, w_dropped, w_ext_adm, w_ext_off,
-                     w_q_int),
-                )
-            ) + (jnp.maximum(acc[6], q_max),)
-            ys = (code, k_next, sojourn, et_cur, et_target, applied)
+            with stages.scope("measure"):
+                new_acc = tuple(
+                    a + w for a, w in zip(
+                        acc[:6],
+                        (w_offered, w_served, w_dropped, w_ext_adm, w_ext_off,
+                         w_q_int),
+                    )
+                ) + (jnp.maximum(acc[6], q_max),)
+                ys = (code, k_next, sojourn, et_cur, et_target, applied)
             if proactive is not None:
                 ys = ys + (use, conf)
             new_carry = (q1, served_prev1, k_next, new_acc)
@@ -2112,7 +2128,7 @@ def make_fused_loop(
                 out["repriced"] = per_tick[-1]
             return new_state, out
 
-        jitted = jax.jit(run, donate_argnums=1)
+        jitted = stages.recorded(jax.jit(run, donate_argnums=1))
         return lambda state: jitted(data, state)
 
     return FusedLoop(n_ticks, init_fn, build), n_ticks
