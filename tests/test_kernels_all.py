@@ -369,6 +369,65 @@ def test_gain_topr_padded_lanes_contribute_zero():
     np.testing.assert_array_equal(base, np.asarray(topr_ref.gain_topr(cand, budget)))
 
 
+def _topr_lane_case(b, n, j, seed=0):
+    """Scenarios in three gain patterns — gains from {0.5, 1.0, 1.5}, so
+    the threshold ties across operators; all zero; normal gains, about a
+    third negative — each under five budgets: 0, 1, exactly its positive
+    count, above it, and one drawn in between."""
+    rng = np.random.default_rng(seed)
+    lane = np.arange(b)
+    pattern = ((lane // 5) % 3)[:, None, None]
+    ties = rng.choice([0.5, 1.0, 1.5], (b, n, j))
+    mixed = rng.normal(0.5, 1.0, (b, n, j))
+    cand = np.where(pattern == 0, ties, np.where(pattern == 1, 0.0, mixed))
+    pos = (cand > 0).sum(axis=(1, 2))
+    pick = lane % 5
+    budget = np.select(
+        [pick == 0, pick == 1, pick == 2, pick == 3],
+        [0, 1, pos, pos + 1 + lane % 7],
+        rng.integers(1, pos + 2),
+    )
+    return cand.astype(np.float32), budget.astype(np.int32)
+
+
+@pytest.mark.parametrize("j", [1, 21, 22, 64])
+@pytest.mark.parametrize("n", [1, 3, 8])
+@pytest.mark.parametrize("b", [128, 200, 1027, 4096])
+def test_gain_topr_lanes_matches_oracle(b, n, j):
+    """The lane layout (scenarios on lanes) takes exactly what the sort
+    oracle takes, B a multiple of the lane tile or not."""
+    cand, budget = _topr_lane_case(b, n, j, seed=b + n + j)
+    got = np.asarray(tk.gain_topr_pallas(cand, budget, interpret=True))
+    np.testing.assert_array_equal(got, np.asarray(topr_ref.gain_topr(cand, budget)))
+
+
+def _pallas_call_names(jaxpr):
+    """Names of every ``pallas_call`` in a closed jaxpr, nested ones too."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _pallas_call_names(sub)
+    return names
+
+
+@pytest.mark.parametrize("shape,layout", [
+    ((1, 3, 22), "gain_topr_pallas"),
+    ((127, 3, 22), "gain_topr_pallas"),
+    ((1, 384, 1024), "gain_topr_pallas"),  # the planner's merged fleet table
+    ((128, 3, 22), "gain_topr_lanes"),
+    ((16384, 3, 22), "gain_topr_lanes"),
+])
+def test_gain_topr_layout_follows_shape(shape, layout):
+    """B < 128 (the planner's B = 1 solve among them) keeps the
+    per-scenario kernel; B >= 128 takes the lane kernel."""
+    args = (jax.ShapeDtypeStruct(shape, jnp.float32),
+            jax.ShapeDtypeStruct(shape[:1], jnp.int32))
+    jaxpr = jax.make_jaxpr(tk.gain_topr_pallas)(*args).jaxpr
+    assert _pallas_call_names(jaxpr) == [layout]
+
+
 # --------------------------------------------------------------------- #
 # compiled-backend lane: real pallas_call on TPU, interpret elsewhere.
 # Deselected by default (pytest.ini); CI's test-kernels-compiled job runs

@@ -47,6 +47,20 @@ CASES = {
     "gain_topr_B1_fleet_rows": (  # plan_batched: one merged [rows, budget] table
         gk.gain_topr_pallas, [((1, 384, 1024), F32), ((1,), I32)],
     ),
+    # The cells' decide shapes: VLD (N = 3, k_max 22) dense, at the
+    # compacted rungs 4096 and 1024, and FPD (k_max 21).
+    "gain_topr_lanes_B16384_J22": (
+        gk.gain_topr_pallas, [((16384, 3, 22), F32), ((16384,), I32)],
+    ),
+    "gain_topr_lanes_B4096_J22": (
+        gk.gain_topr_pallas, [((4096, 3, 22), F32), ((4096,), I32)],
+    ),
+    "gain_topr_lanes_B1024_J22": (
+        gk.gain_topr_pallas, [((1024, 3, 22), F32), ((1024,), I32)],
+    ),
+    "gain_topr_lanes_B16384_J21": (
+        gk.gain_topr_pallas, [((16384, 3, 21), F32), ((16384,), I32)],
+    ),
     "decide_fused_B16384_k64": (
         functools.partial(dk.batch_decide_pallas, k_hi=K),
         [((B, N), F32)] * 6 + [((B,), I32)],
@@ -94,4 +108,7 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     kernel, shapes = CASES[name]
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(kernel).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if name.startswith("gain_topr"):  # the layout follows B: lanes from 128 up
+        assert ("gain_topr_lanes" in text) == (shapes[0][0][0] >= 128)
