@@ -51,8 +51,10 @@ class OpDef:
     measurer corrects it online.  ``fn`` is the live-engine compute:
     ``fn(payload) -> list[(downstream_name, payload)]`` (may be ``None``
     for model-only / DES graphs).  ``scaling`` selects how k processors
-    compose — ``"replica"`` (k independent servers, exact M/M/k) or
-    ``"group"`` (one gang of k chips at ``mu * k * eff(k)``, DESIGN.md §2).
+    compose — ``"replica"`` (k independent servers, exact M/M/k),
+    ``"group"`` (one gang of k chips at ``mu * k * eff(k)``, DESIGN.md §2)
+    or ``"keyed"`` (k hash partitions of a keyed stream, each M/M/1, the
+    hot key carrying ``hot_share`` of the input, DESIGN.md §20).
     ``service_kind``/``service_cv`` choose the DES service-time
     distribution used when the graph is bound to the simulator.
     """
@@ -66,6 +68,7 @@ class OpDef:
     max_k: int = 1 << 30
     service_kind: str = "exponential"
     service_cv: float = 1.0
+    hot_share: float | None = None
 
     def spec(self, mu: float | None = None) -> OperatorSpec:
         """Compile to the core model's operator description."""
@@ -76,6 +79,7 @@ class OpDef:
             group_alpha=self.group_alpha,
             min_k=self.min_k,
             max_k=self.max_k,
+            hot_share=0.0 if self.hot_share is None else self.hot_share,
         )
 
 
@@ -138,9 +142,21 @@ class AppGraph:
                 raise GraphValidationError(
                     f"operator {op.name!r}: service rate mu must be > 0, got {op.mu}"
                 )
-            if op.scaling not in ("replica", "group"):
+            if op.scaling not in ("replica", "group", "keyed"):
                 raise GraphValidationError(
                     f"operator {op.name!r}: unknown scaling {op.scaling!r}"
+                )
+            if op.scaling == "keyed":
+                h = op.hot_share
+                if h is None or not 0.0 <= h <= 1.0:
+                    raise GraphValidationError(
+                        f"operator {op.name!r}: keyed scaling needs a hot_share "
+                        f"in [0, 1], got {h}"
+                    )
+            elif op.hot_share is not None:
+                raise GraphValidationError(
+                    f"operator {op.name!r}: hot_share applies to keyed scaling "
+                    f"only, not {op.scaling!r}"
                 )
 
         n = len(self.ops)
@@ -255,6 +271,13 @@ class AppGraph:
         """(scaling mode, group_alpha) per operator, index-ordered — the
         scheduler's view of how processors compose."""
         return [op.scaling for op in self.ops], [op.group_alpha for op in self.ops]
+
+    def hot_shares(self) -> np.ndarray:
+        """Each keyed operator's hot-key share, index-ordered; NaN where the
+        operator is not keyed."""
+        return np.array(
+            [np.nan if op.hot_share is None else op.hot_share for op in self.ops]
+        )
 
     # Derivation -------------------------------------------------------- #
     def with_sources(self, sources: Mapping[str, float]) -> "AppGraph":

@@ -64,6 +64,7 @@ class OperatorArrays:
     alpha: np.ndarray  # group efficiency rolloff [N]
     min_k: np.ndarray  # per-operator floor [N]
     lam0_total: float
+    hot: np.ndarray  # keyed scaling's hot-key share [N]; NaN = not keyed
 
 
 def operator_arrays(top: Topology) -> OperatorArrays:
@@ -75,6 +76,10 @@ def operator_arrays(top: Topology) -> OperatorArrays:
         alpha=np.array([op.group_alpha for op in ops], dtype=np.float64),
         min_k=np.array([op.min_k for op in ops], dtype=np.int64),
         lam0_total=top.lam0_total,
+        hot=np.array(
+            [op.hot_share if op.scaling == "keyed" else np.nan for op in ops],
+            dtype=np.float64,
+        ),
     )
 
 
@@ -85,7 +90,8 @@ def sojourn_table(top: Topology, k_hi: int) -> np.ndarray:
     """``T[i, k] = E[T_i](k)`` for ``k in [0, k_hi]`` — ``[N, k_hi+1]`` float64.
 
     Entries below the operator's ``min_k`` or in the unstable region
-    (``k*mu <= lam`` replica / ``mu_eff(k) <= lam`` group) are ``+inf``,
+    (``k*mu <= lam`` replica / ``mu_eff(k) <= lam`` group / the hot
+    partition's ``lam * p_hot(k) >= mu`` keyed) are ``+inf``,
     mirroring ``OperatorSpec.sojourn`` exactly: the vectorized recursion
     performs the same float64 operations in the same order as the scalar
     ``erlang.expected_sojourn``, so finite entries are bit-identical to the
@@ -98,7 +104,8 @@ def sojourn_table(top: Topology, k_hi: int) -> np.ndarray:
     n = arr.lam.shape[0]
     T = np.full((n, k_hi + 1), np.inf, dtype=np.float64)
 
-    rep = ~arr.group
+    keyed = ~np.isnan(arr.hot)
+    rep = ~arr.group & ~keyed
     if rep.any():
         lam, mu = arr.lam[rep], arr.mu[rep]
         a = lam / mu
@@ -151,10 +158,30 @@ def sojourn_table(top: Topology, k_hi: int) -> np.ndarray:
             row[stable] = t[stable]
             T[i] = row
 
+    if keyed.any():
+        T[keyed] = _keyed_table(
+            np, arr.lam[keyed], arr.mu[keyed], arr.hot[keyed], k_hi
+        )
+
     for i in range(n):
         lo = min(int(arr.min_k[i]), k_hi + 1)
         T[i, :lo] = np.inf
     return T
+
+
+def _keyed_table(xp, lam, mu, hot, k_hi: int, dtype=None):
+    """``[R, k_hi+1]`` sojourn of R keyed operators (DESIGN.md §20): k M/M/1
+    partitions, the hottest at ``p_hot = h + (1 - h)/k`` of the input.  The
+    same float operations as ``erlang.keyed_sojourn``, for numpy (float64)
+    and jnp alike."""
+    ks = xp.arange(k_hi + 1, dtype=dtype or lam.dtype)[None, :]
+    lam, mu, hot = lam[:, None], mu[:, None], hot[:, None]
+    with np.errstate(divide="ignore", invalid="ignore", over="ignore"):
+        p_cold = (1.0 - hot) / xp.maximum(ks, 1.0)
+        p_hot = hot + p_cold
+        lam_hot = lam * p_hot
+        t = p_hot / (mu - lam_hot) + (ks - 1.0) * p_cold / (mu - lam * p_cold)
+    return xp.where((ks >= 1.0) & (lam_hot < mu), t, xp.inf)
 
 
 def gain_table(top: Topology, k_hi: int) -> tuple[np.ndarray, np.ndarray]:
@@ -244,6 +271,7 @@ def sojourn_table_jax(
     group=None,
     alpha=None,
     min_k=None,
+    hot=None,
     interpret: bool = False,
     force_kernel: bool = False,
     unroll: int = 1,
@@ -257,7 +285,9 @@ def sojourn_table_jax(
     Group-scaled operators use the M/M/1 closed form and are merged in
     with ``jnp.where`` so the whole function stays traceable.  ``unroll``
     tunes the reference scan's unroll factor — bitwise-safe, so callers
-    may autotune it freely (kernels/decide_fused does).
+    may autotune it freely (kernels/decide_fused does).  ``hot`` (``[N]``,
+    NaN where an operator is not keyed) merges in the keyed closed form
+    (DESIGN.md §20); ``None`` traces none of it.
     """
     import jax.numpy as jnp
 
@@ -297,6 +327,11 @@ def sojourn_table_jax(
     t_grp = jnp.where(a_grp < 1.0, t_grp, jnp.inf)
 
     T = jnp.where(group[:, None], t_grp, t_rep)
+    if hot is not None:
+        hot = jnp.asarray(hot, dtype=dtype)
+        keyed = ~jnp.isnan(hot)
+        t_key = _keyed_table(jnp, lam, mu, jnp.where(keyed, hot, 0.0), k_hi, dtype)
+        T = jnp.where(keyed[:, None], t_key, T)
     return jnp.where(kk >= min_k[:, None], T, jnp.inf)
 
 
@@ -315,6 +350,7 @@ def expected_sojourn_batch_jax(top: Topology, k_batch, *, interpret: bool = Fals
         group=arr.group,
         alpha=arr.alpha,
         min_k=arr.min_k,
+        hot=arr.hot if not np.isnan(arr.hot).all() else None,
         interpret=interpret,
     )
     per_op = jnp.take_along_axis(

@@ -124,7 +124,9 @@ class ControllerStatic:
 
     ``names`` keeps each scenario's operator names (reason strings +
     Topology reconstruction); array lanes beyond ``n_ops[b]`` are inert
-    padding (no routing, no arrivals, ``active`` False).
+    padding (no routing, no arrivals, ``active`` False).  ``hot`` holds
+    each keyed operator's hot-key share (DESIGN.md §20), NaN elsewhere;
+    ``None`` means no operator is keyed.
     """
 
     base_routing: np.ndarray  # [B, N, N] declared multiplicities
@@ -134,6 +136,7 @@ class ControllerStatic:
     speed: np.ndarray  # [B, N] machine-class speed factors (1 = reference)
     n_ops: np.ndarray  # [B] operators per scenario
     names: tuple  # per-scenario tuple of operator names
+    hot: np.ndarray | None = None  # [B, N] keyed hot-key share, NaN = not keyed
 
     @property
     def batch(self) -> int:
@@ -142,6 +145,11 @@ class ControllerStatic:
     @property
     def n(self) -> int:
         return self.base_routing.shape[1]
+
+    @property
+    def keyed(self) -> bool:
+        """Whether any operator of any lane is keyed."""
+        return self.hot is not None and bool((~np.isnan(self.hot)).any())
 
     @classmethod
     def from_graphs(cls, graphs: Sequence, *, speed=None) -> "ControllerStatic":
@@ -153,6 +161,7 @@ class ControllerStatic:
         alpha = np.zeros((b, n))
         active = np.zeros((b, n), dtype=bool)
         spd = np.ones((b, n))
+        hot = np.full((b, n), np.nan)
         n_ops = np.zeros(b, dtype=np.int64)
         names = []
         for bi, g in enumerate(graphs):
@@ -161,12 +170,13 @@ class ControllerStatic:
             scaling, ga = g.scaling_lists()
             group[bi, :ni] = [s == "group" for s in scaling]
             alpha[bi, :ni] = ga
+            hot[bi, :ni] = g.hot_shares()
             active[bi, :ni] = True
             n_ops[bi] = ni
             names.append(tuple(g.names))
             if speed is not None and speed[bi] is not None:
                 spd[bi, :ni] = speed[bi]
-        return cls(routing, group, alpha, active, spd, n_ops, tuple(names))
+        return cls(routing, group, alpha, active, spd, n_ops, tuple(names), hot)
 
 
 @dataclass(frozen=True)
@@ -239,6 +249,8 @@ def pad_static(static: ControllerStatic, b_total: int) -> ControllerStatic:
         speed=np.concatenate([static.speed, np.ones((pad, n))]),
         n_ops=np.concatenate([static.n_ops, np.zeros(pad, dtype=np.int64)]),
         names=static.names + ((),) * pad,
+        hot=None if static.hot is None
+        else np.concatenate([static.hot, np.full((pad, n), np.nan)]),
     )
 
 
@@ -333,10 +345,12 @@ class DecideCache(NamedTuple):
     et_cur: Any  # [B] cached E[T] at entry allocation
     et_target: Any  # [B] cached E[T] at proposed allocation
     applied: Any  # [B] bool cached applied flag
+    hot_floor: Any = None  # [B] int32 cached counter (keyed graphs only)
 
 
-def init_decide_cache(b: int, n: int, *, dtype=None) -> DecideCache:
-    """Cold (all-lanes-invalid) cache — the first tick prices densely."""
+def init_decide_cache(b: int, n: int, *, dtype=None, keyed: bool = False) -> DecideCache:
+    """Cold (all-lanes-invalid) cache — the first tick prices densely.
+    ``keyed`` adds the slot of the keyed decide's ``hot_floor`` output."""
     import jax.numpy as jnp
 
     dtype = jnp.zeros((), dtype=dtype).dtype  # canonical under the x64 flag
@@ -352,6 +366,7 @@ def init_decide_cache(b: int, n: int, *, dtype=None) -> DecideCache:
         et_cur=jnp.zeros(b, dtype=dtype),
         et_target=jnp.zeros(b, dtype=dtype),
         applied=jnp.zeros(b, dtype=bool),
+        hot_floor=jnp.zeros(b, dtype=jnp.int32) if keyed else None,
     )
 
 
@@ -401,12 +416,34 @@ def _bucketed(ladder, b, mask, run_at_width, templates):
     return jax.lax.switch(sel, [branch(w) for w in ladder], 0)
 
 
+def _capacity_jax(st, mu_eff, k_floor):
+    """Per-operator capacity at ``k_floor`` (``k`` floored at 1): k
+    replicas, a gang at ``eff(k)``, or a keyed operator's hot partition
+    (DESIGN.md §20; traced only when ``st`` holds keyed operators)."""
+    import jax.numpy as jnp
+
+    eff = 1.0 / (1.0 + st["alpha"] * (k_floor - 1.0))
+    capacity = jnp.where(st["group"], mu_eff * k_floor * eff, mu_eff * k_floor)
+    if "hot" in st:
+        capacity = _keyed_capacity_jax(st, capacity, mu_eff, k_floor)
+    return capacity
+
+
+def _keyed_capacity_jax(st, capacity, mu_eff, k_floor):
+    """``capacity`` with each keyed operator's replaced by its hot
+    partition's, ``mu / (h + (1 - h)/k)`` (``k_floor`` >= 1)."""
+    import jax.numpy as jnp
+
+    hot = st["hot"]
+    return jnp.where(st["keyed"], mu_eff / (hot + (1.0 - hot) / k_floor), capacity)
+
+
 def _make_compact_decide(core, b: int, ladder: tuple[int, ...]):
     """Wrap a dense decide core with the trigger scan + bucketed dispatch.
 
     ``decide(st, lam_hat, mu_hat, drop_hat, lam0_hat, k_current, cache)
-    -> ((code, k_next, et_cur, et_target, applied), repriced, cache')``
-    is bitwise identical to ``core(...)`` on every output: active lanes
+    -> ((code, k_next, et_cur, et_target, applied[, hot_floor]), repriced,
+    cache')`` is bitwise identical to ``core(...)`` on every output: active lanes
     are gathered, priced at the compacted width, and scattered back;
     quiet lanes replay their cached row, which purity guarantees equals
     a fresh repricing (see :class:`CompactionConfig`).
@@ -426,10 +463,7 @@ def _make_compact_decide(core, b: int, ladder: tuple[int, ...]):
             # --- trigger scan: O(B*N), no table/solve/top-R work ----------- #
             mu_eff = mu_hat * st["speed"]
             k_floor = jnp.maximum(k_in, 1).astype(lam_hat.dtype)
-            eff = 1.0 / (1.0 + st["alpha"] * (k_floor - 1.0))
-            capacity = jnp.where(
-                st["group"], mu_eff * k_floor * eff, mu_eff * k_floor
-            )
+            capacity = _capacity_jax(st, mu_eff, k_floor)
             valid = jnp.isfinite(lam_hat) & jnp.isfinite(mu_eff) & (mu_eff > 0)
             drops = jnp.nan_to_num(drop_hat, nan=0.0)
             hot = (
@@ -454,18 +488,17 @@ def _make_compact_decide(core, b: int, ladder: tuple[int, ...]):
                     st_g, lam_hat[g], mu_hat[g], drop_hat[g], lam0_hat[g], k_in[g]
                 )
 
-            code, k_next, et_cur, et_target, applied = _bucketed(
+            keyed = () if cache.hot_floor is None else (cache.hot_floor,)
+            outs = _bucketed(
                 ladder, b, repriced, price,
                 (cache.code, cache.k_next, cache.et_cur, cache.et_target,
-                 cache.applied),
+                 cache.applied) + keyed,
             )
             new_cache = DecideCache(
-                ok=jnp.ones_like(cache.ok),
-                lam=lam_hat, mu=mu_hat, drop=drop_hat, lam0=lam0_hat, k=k_in,
-                code=code, k_next=k_next, et_cur=et_cur, et_target=et_target,
-                applied=applied,
+                jnp.ones_like(cache.ok), lam_hat, mu_hat, drop_hat, lam0_hat, k_in,
+                *outs,
             )
-        return (code, k_next, et_cur, et_target, applied), repriced, new_cache
+        return outs, repriced, new_cache
 
     return decide
 
@@ -549,24 +582,30 @@ def _source_mask(static: ControllerStatic) -> np.ndarray:
     return src
 
 
-def effective_capacity(k, mu_eff, group, alpha) -> np.ndarray:
+def effective_capacity(k, mu_eff, group, alpha, hot=None) -> np.ndarray:
     """Per-operator service capacity at allocation ``k`` with the group
     efficiency curve applied (k floored at 1, mirroring the scalar
-    ``overloaded_mask``)."""
+    ``overloaded_mask``); a keyed operator's (``hot`` finite, DESIGN.md
+    §20) is where its hot partition saturates, ``mu / (h + (1 - h)/k)``."""
     k_eff = np.maximum(np.asarray(k, dtype=np.int64), 1)
     with np.errstate(divide="ignore", invalid="ignore"):
         eff = 1.0 / (1.0 + alpha * (k_eff - 1))
-    return np.where(group, mu_eff * k_eff * eff, mu_eff * k_eff)
+    cap = np.where(group, mu_eff * k_eff * eff, mu_eff * k_eff)
+    if hot is not None:
+        with np.errstate(invalid="ignore"):
+            keyed_cap = mu_eff / (hot + (1.0 - hot) / k_eff)
+        cap = np.where(np.isnan(hot), cap, keyed_cap)
+    return cap
 
 
-def overloaded_mask_batch(lam_hat, mu_eff, drop, k, group, alpha) -> np.ndarray:
+def overloaded_mask_batch(lam_hat, mu_eff, drop, k, group, alpha, hot=None) -> np.ndarray:
     """[B, N] bool: measured offered load >= capacity, or sustained
     shedding — the vectorized twin of ``DRSScheduler.overloaded_mask``
     (same comparisons, so bit-identical decisions at any batch size)."""
     lam_hat = np.asarray(lam_hat, dtype=np.float64)
     mu_eff = np.asarray(mu_eff, dtype=np.float64)
     drops = np.nan_to_num(np.asarray(drop, dtype=np.float64), nan=0.0)
-    capacity = effective_capacity(k, mu_eff, group, alpha)
+    capacity = effective_capacity(k, mu_eff, group, alpha, hot)
     valid = np.isfinite(lam_hat) & np.isfinite(mu_eff) & (mu_eff > 0)
     with np.errstate(invalid="ignore"):
         hot = (lam_hat >= capacity * (1.0 - 1e-9)) | (
@@ -612,13 +651,16 @@ def clamp_row(
     scaling: Sequence[str],
     group_alpha: Sequence[float],
     speed: np.ndarray | None = None,
+    hot_share: Sequence[float] | None = None,
 ) -> Topology:
     """Rebuild one scenario's model from measurements (DESIGN.md §4/§11).
 
     This is the pure-function extraction of ``DRSScheduler.topology_from``
     — identical float ops, so the rebuilt Topology is bit-identical to the
     pre-extraction scheduler's.  ``speed`` applies machine-class factors
-    to the effective per-processor service rates (1.0 = reference class).
+    to the effective per-processor service rates (1.0 = reference class);
+    ``hot_share`` gives keyed operators their hot-key shares (NaN
+    elsewhere; DESIGN.md §20).
     """
     n = len(names)
     hot = bool(np.asarray(overloaded).any())
@@ -652,6 +694,8 @@ def clamp_row(
             mu=float(mu_hat[i]) if speed is None else float(mu_hat[i] * speed[i]),
             scaling=scaling[i],
             group_alpha=group_alpha[i],
+            hot_share=0.0 if hot_share is None or np.isnan(hot_share[i])
+            else float(hot_share[i]),
         )
         for i in range(n)
     ]
@@ -755,9 +799,11 @@ def decide_single(
         else:
             group = np.array([op.scaling == "group" for op in top.operators])
             alpha = np.array([op.group_alpha for op in top.operators])
+            hot = np.array([op.hot_share if op.scaling == "keyed" else np.nan
+                            for op in top.operators])
             overloaded = overloaded_mask_batch(
                 lam_hat[None], mu_hat[None], None if drop is None else drop[None],
-                k_current[None], group[None], alpha[None],
+                k_current[None], group[None], alpha[None], hot[None],
             )[0]
 
     # --- Overload: defined unstable-snapshot path (no gates) ------------ #
@@ -924,10 +970,16 @@ def tick_batch(
     decide); the ``need`` diagnostic defaults to 0 on unpriced lanes.
     """
     b, n = static.batch, static.n
+    if proactive is not None and static.keyed:
+        raise ValueError(
+            "the MPC planner has no keyed-operator model (DESIGN.md §20); "
+            "decide keyed graphs reactively"
+        )
     k_current = np.asarray(k_current, dtype=np.int64)
     mu_eff = meas.mu_hat * static.speed
     overloaded = overloaded_mask_batch(
-        meas.lam_hat, mu_eff, meas.drop_hat, k_current, static.group, static.alpha
+        meas.lam_hat, mu_eff, meas.drop_hat, k_current, static.group, static.alpha,
+        static.hot,
     ) & static.active
     hot = overloaded.any(axis=1)
     capped = np.zeros((b, n), dtype=bool)
@@ -1058,7 +1110,13 @@ def tick_batch(
             compact_state.replayed[bi] = True
             continue
         names = static.names[bi]
-        scaling = ["group" if g else "replica" for g in static.group[bi, :ni]]
+        hot_row = None if static.hot is None else static.hot[bi, :ni]
+        scaling = [
+            "group" if static.group[bi, i]
+            else "keyed" if hot_row is not None and not np.isnan(hot_row[i])
+            else "replica"
+            for i in range(ni)
+        ]
         t_max = params.t_max[bi]
         try:
             top = clamp_row(
@@ -1073,6 +1131,7 @@ def tick_batch(
                 static.alpha[bi, :ni],
                 speed=None if np.all(static.speed[bi, :ni] == 1.0)
                 else static.speed[bi, :ni],
+                hot_share=hot_row,
             )
             row = decide_single(
                 top,
@@ -1136,8 +1195,10 @@ def _decide_statics(static: ControllerStatic, params: ControllerParams) -> dict:
     whole bundle with one rule (``P(axis, None, ...)``) — this is what
     lets the decide run under ``shard_map`` with the statics passed as
     explicit (sharded) arguments instead of replicated closure constants.
+    Keyed graphs (DESIGN.md §20) add ``keyed`` and ``hot``; others carry
+    neither, so their programs are unchanged.
     """
-    return {
+    st = {
         "routing0": np.asarray(static.base_routing, dtype=np.float64),
         "group": np.asarray(static.group, dtype=bool),
         "alpha": np.asarray(static.alpha, dtype=np.float64),
@@ -1148,6 +1209,10 @@ def _decide_statics(static: ControllerStatic, params: ControllerParams) -> dict:
         "min_improvement": np.asarray(params.min_improvement, dtype=np.float64),
         "horizon": np.asarray(params.horizon_seconds, dtype=np.float64),
     }
+    if static.keyed:
+        st["keyed"] = ~np.isnan(static.hot)
+        st["hot"] = np.where(st["keyed"], static.hot, 0.0)
+    return st
 
 
 def _make_decide_core(
@@ -1193,6 +1258,7 @@ def _make_decide_core(
         k_max = st["k_max"]
         min_improvement = st["min_improvement"]
         horizon = st["horizon"]
+        keyed = st.get("keyed")  # None: no keyed operator, nothing traced
         b = lam_hat.shape[0]
         dtype = lam_hat.dtype
         # --- overload trigger + capped propagation (§11) --------------- #
@@ -1200,8 +1266,7 @@ def _make_decide_core(
             mu_eff = mu_hat * speed
             k_cur = k_current.astype(jnp.int32)
             k_floor = jnp.maximum(k_cur, 1).astype(dtype)
-            eff = 1.0 / (1.0 + alpha * (k_floor - 1.0))
-            capacity = jnp.where(group, mu_eff * k_floor * eff, mu_eff * k_floor)
+            capacity = _capacity_jax(st, mu_eff, k_floor)
             valid = jnp.isfinite(lam_hat) & jnp.isfinite(mu_eff) & (mu_eff > 0)
             drops = jnp.nan_to_num(drop_hat, nan=0.0)
             overloaded = valid & active & (
@@ -1264,6 +1329,8 @@ def _make_decide_core(
                     lam.reshape(-1), mu_eff.reshape(-1), k_hi=k_hi,
                     group=group.reshape(-1), alpha=alpha.reshape(-1),
                     min_k=jnp.ones(b * n, dtype=jnp.int32),
+                    hot=jnp.where(keyed, st["hot"], jnp.nan).reshape(-1)
+                    if keyed is not None else None,
                     interpret=interpret, force_kernel=force_kernel,
                 ).reshape(b, n, k_hi + 1)
                 G = lam[..., None] * (T[..., :-1] - T[..., 1:])
@@ -1276,6 +1343,13 @@ def _make_decide_core(
                 k_start = jnp.where(active, jnp.where(has_finite, first, k_hi + 1), 0)
                 floor_total = k_start.sum(axis=-1)
                 infeasible = solve_bad | (floor_total > k_max)
+                if keyed is not None:
+                    # Keyed operators whose floor the hot partition set:
+                    # above the pooled M/M/k floor floor(lam/mu) + 1.
+                    pooled = jnp.floor(lam / mu_eff) + 1.0
+                    hot_floor = (
+                        keyed & active & (k_start.astype(dtype) > pooled)
+                    ).sum(axis=-1, dtype=jnp.int32)
 
             # --- Program (4): masked top-R over the gain table ---------- #
             with stages.scope("candidates"):
@@ -1315,9 +1389,14 @@ def _make_decide_core(
                 jnp.inf,
             )
             visit = lam / jnp.maximum(lam0_total, 1e-300)[:, None]
+            cap4 = k4.astype(dtype) * mu_eff
+            if keyed is not None:
+                cap4 = _keyed_capacity_jax(
+                    st, cap4, mu_eff, jnp.maximum(k4, 1).astype(dtype)
+                )
             cap_new = jnp.where(
                 active,
-                k4.astype(dtype) * mu_eff / jnp.maximum(visit, 1e-12),
+                cap4 / jnp.maximum(visit, 1e-12),
                 jnp.inf,
             ).min(axis=-1)
             slack = jnp.maximum(cap_new - lam0_total, 1e-9)
@@ -1352,6 +1431,8 @@ def _make_decide_core(
             )
             k_next = jnp.where(apply_mask[:, None], k4, k_cur)
             et_target = jnp.where(feasible4, et4, jnp.inf)
+        if keyed is not None:
+            return code, k_next, et_cur, et_target, apply_mask, hot_floor
         return code, k_next, et_cur, et_target, apply_mask
 
     return decide
@@ -1411,6 +1492,13 @@ def make_decide_jax(
     only the work placement changes.  Under a mesh the compaction runs
     per shard inside ``shard_map`` (no cross-device gather) and the
     cache keeps the padded extent.
+
+    A static graph with a keyed operator (DESIGN.md §20) prices it by its
+    hot partition and adds a sixth output, ``hot_floor [B]`` int32: the
+    keyed operators per lane whose least stable allocation lies above the
+    pooled M/M/k floor ``floor(lam/mu) + 1``.  Graphs without one trace
+    none of it.  ``kernels/decide_fused`` has no keyed table: asking for
+    it with a keyed operator raises ``ValueError``.
     """
     import jax
     import jax.numpy as jnp
@@ -1423,6 +1511,12 @@ def make_decide_jax(
     )
     if fused is None:
         fused = bool(getattr(params, "fused_decide", False))
+    keyed = static.keyed
+    if fused and keyed:
+        raise ValueError(
+            "kernels/decide_fused has no keyed-operator table (DESIGN.md §20): "
+            "decide graphs with scaling='keyed' with fused=False"
+        )
     # Exactness bound for the fused path's candidate-window truncation:
     # every scenario's Program-4 budget is <= its k_max, so the fleet max
     # caps the window (ref.py proof) — static because params is static.
@@ -1449,7 +1543,7 @@ def make_decide_jax(
                 )
 
             decide_compact.init_cache = lambda dtype=None: init_decide_cache(
-                b, n, dtype=dtype
+                b, n, dtype=dtype, keyed=keyed
             )
             return decide_compact
 
@@ -1470,6 +1564,7 @@ def make_decide_jax(
     row = P(axis, None)
     lane = P(axis)
     pad = b_pad - b
+    out_specs = (lane, row, lane, lane, lane) + ((lane,) if keyed else ())
 
     if compact:
         # Per-shard compaction: each device runs the trigger scan and the
@@ -1482,12 +1577,13 @@ def make_decide_jax(
         cache_specs = DecideCache(
             ok=lane, lam=row, mu=row, drop=row, lam0=lane, k=row,
             code=lane, k_next=row, et_cur=lane, et_target=lane, applied=lane,
+            hot_floor=lane if keyed else None,
         )
         sharded_c = jax.shard_map(
             core_c,
             mesh=mesh,
             in_specs=(st_specs, row, row, row, lane, row, cache_specs),
-            out_specs=((lane, row, lane, lane, lane), lane, cache_specs),
+            out_specs=(out_specs, lane, cache_specs),
             check_vma=False,
         )
 
@@ -1520,7 +1616,7 @@ def make_decide_jax(
 
         # The cache lives at the PADDED extent (it is a shard_map operand).
         decide_compact.init_cache = lambda dtype=None: init_decide_cache(
-            b_pad, n, dtype=dtype
+            b_pad, n, dtype=dtype, keyed=keyed
         )
         return decide_compact
 
@@ -1528,7 +1624,7 @@ def make_decide_jax(
         core,
         mesh=mesh,
         in_specs=(st_specs, row, row, row, lane, row),
-        out_specs=(lane, row, lane, lane, lane),
+        out_specs=out_specs,
         check_vma=False,
     )
 
@@ -1700,8 +1796,13 @@ def make_fused_loop(
     from jax import lax
 
     from ..streaming.batchsim import composed_wait as _composed_wait
-    from ..streaming.batchsim import window_step_fn
+    from ..streaming.batchsim import refuse_keyed, window_step_fn
 
+    if static.keyed:
+        refuse_keyed([
+            name for names, row in zip(static.names, static.hot)
+            for name, h in zip(names, row) if not np.isnan(h)
+        ])
     b_real, n = static.batch, static.n
     dt = float(arrays.dt)
     steps = arrays.steps

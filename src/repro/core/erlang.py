@@ -22,6 +22,18 @@ Two implementations are provided:
 
 Both return ``math.inf`` when the operator is unstable (``k*mu <= lam``),
 matching the paper's Eq. (1) second branch.
+
+A **keyed** operator (DESIGN.md §20) is not one queue: its k processors
+are k hash partitions of a keyed stream, each an M/M/1 queue.  The
+current hot key carries a share ``h`` of the input and the rest hashes
+evenly, so the hottest partition takes ``p_hot = h + (1 - h)/k`` of it and
+each other partition ``p_cold = (1 - h)/k``.  A tuple's mean sojourn is
+
+    T(k) = p_hot / (mu - lam p_hot) + (k - 1) p_cold / (mu - lam p_cold),
+
+infinite where ``lam p_hot >= mu``: the hot partition, not the pooled
+load, bounds the capacity at ``mu / p_hot``.  ``h = 1`` is a global
+aggregate that no processor count helps; ``h = 0`` hashes evenly.
 """
 
 from __future__ import annotations
@@ -37,6 +49,8 @@ __all__ = [
     "expected_sojourn",
     "expected_sojourn_factorial",
     "expected_queue_delay",
+    "keyed_capacity",
+    "keyed_sojourn",
     "min_stable_k",
     "sojourn_curve",
     "marginal_benefit",
@@ -114,8 +128,34 @@ def expected_sojourn_factorial(k: int, lam: float, mu: float) -> float:
     return wait + 1.0 / mu
 
 
-def min_stable_k(lam: float, mu: float) -> int:
+def keyed_capacity(k: int, mu: float, hot: float) -> float:
+    """Input rate at which a keyed operator's hot partition, which takes
+    ``h + (1 - h)/k`` of it (k floored at 1), saturates."""
+    return mu / (hot + (1.0 - hot) / max(k, 1))
+
+
+def keyed_sojourn(k: int, lam: float, mu: float, hot: float) -> float:
+    """E[T](k) of a keyed operator: k M/M/1 partitions, one of them hot
+    (module docstring).  +inf when k < 1 or the hot partition is
+    unstable.  The float operations are those of the batched tables
+    (``core/batched.py``), so their finite entries are bit-identical."""
+    if k < 1:
+        return math.inf
+    p_cold = (1.0 - hot) / k
+    p_hot = hot + p_cold
+    lam_hot = lam * p_hot
+    if not lam_hot < mu:
+        return math.inf
+    return p_hot / (mu - lam_hot) + (k - 1) * p_cold / (mu - lam * p_cold)
+
+
+def min_stable_k(lam: float, mu: float, hot: float | None = None) -> int:
     """Smallest k with finite E[T]: ceil(lam/mu), bumped when lam/mu is integral.
+
+    With ``hot`` (a keyed operator's hot-key share) the hot partition sets
+    it instead: the least k with ``lam (h + (1 - h)/k) < mu``, which is
+    ``floor(lam (1 - h) / (mu - lam h)) + 1``.  Raises ``ValueError`` where
+    the hot key alone saturates a processor (``lam h >= mu``).
 
     Paper Algorithm 1 initialises k_i = ceil(lam_i/mu_i); when lam/mu is an
     exact integer that k gives k*mu == lam which is *unstable*, so one more
@@ -123,6 +163,19 @@ def min_stable_k(lam: float, mu: float) -> int:
     glosses this; its Eq. (1) makes k = lam/mu infinite, and the while-loop
     would immediately add the extra processor anyway.)
     """
+    if hot is not None:
+        if not lam * hot < mu:
+            raise ValueError(
+                f"hot key carries {lam * hot:.6g} tuples/s >= mu = {mu:.6g}: "
+                "no partition count keeps the hot partition stable"
+            )
+        k = max(math.floor(lam * (1.0 - hot) / (mu - lam * hot)) + 1, 1)
+        # The closed form rounds; settle on the table's own stability test.
+        while k > 1 and math.isfinite(keyed_sojourn(k - 1, lam, mu, hot)):
+            k -= 1
+        while not math.isfinite(keyed_sojourn(k, lam, mu, hot)):
+            k += 1
+        return k
     if lam == 0.0:
         return 1
     a = lam / mu

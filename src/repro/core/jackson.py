@@ -27,7 +27,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from .erlang import expected_sojourn, min_stable_k
+from .erlang import expected_sojourn, keyed_sojourn, min_stable_k
 
 __all__ = [
     "OperatorSpec",
@@ -52,6 +52,9 @@ class OperatorSpec:
     * ``"group"``   — the k processors form one gang (e.g. one pjit'd chip
       group); service rate is ``mu * k * group_efficiency(k)`` on an M/M/1
       queue.  See DESIGN.md §2 — this is the TPU chip-group extension.
+    * ``"keyed"``   — the k processors are k hash partitions of a keyed
+      stream, each M/M/1, with the hot key's share ``hot_share`` of the
+      input on one of them (DESIGN.md §20).
     """
 
     name: str
@@ -62,6 +65,7 @@ class OperatorSpec:
     group_alpha: float = 0.0
     min_k: int = 1
     max_k: int = 1 << 30
+    hot_share: float = 0.0  # keyed mode: the hot key's share of the input
 
     def sojourn(self, k: int, lam: float) -> float:
         """E[T_i](k) for this operator under arrival rate lam."""
@@ -72,12 +76,19 @@ class OperatorSpec:
         if self.scaling == "group":
             eff = 1.0 / (1.0 + self.group_alpha * (k - 1))
             return expected_sojourn(1, lam, self.mu * k * eff)
+        if self.scaling == "keyed":
+            return keyed_sojourn(k, lam, self.mu, self.hot_share)
         raise ValueError(f"unknown scaling {self.scaling!r}")
 
     def min_feasible_k(self, lam: float) -> int:
         """Smallest k with finite sojourn (Algorithm 1 line 2 init)."""
         if self.scaling == "replica":
             return max(self.min_k, min_stable_k(lam, self.mu))
+        if self.scaling == "keyed":
+            try:
+                return max(self.min_k, min_stable_k(lam, self.mu, self.hot_share))
+            except ValueError as e:  # the hot key alone saturates a processor
+                raise UnstableTopologyError(f"operator {self.name}: {e}") from e
         # group: need mu * k * eff(k) > lam.  With eff(k) = 1/(1+alpha(k-1))
         # the effective rate ASYMPTOTES at mu/alpha as k -> inf, so a load
         # beyond that is unreachable at any k — fail fast instead of

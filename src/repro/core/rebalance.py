@@ -28,6 +28,8 @@ from typing import Any, Callable
 
 import numpy as np
 
+from .erlang import keyed_capacity
+
 __all__ = ["ExecutableCache", "RebalanceCostModel", "RebalancePlan"]
 
 
@@ -164,7 +166,11 @@ class RebalanceCostModel:
         et_new = topology.expected_sojourn(k_new)
         lam0 = topology.lam0_total
         mus = np.array([op.mu for op in topology.operators])
-        capacity_new = float(np.min(k_new * mus / np.maximum(topology.visit_counts, 1e-12)))
+        caps = k_new * mus
+        for i, op in enumerate(topology.operators):
+            if op.scaling == "keyed":  # the hot partition saturates first
+                caps[i] = keyed_capacity(int(k_new[i]), op.mu, op.hot_share)
+        capacity_new = float(np.min(caps / np.maximum(topology.visit_counts, 1e-12)))
         slack = max(capacity_new - lam0, 1e-9)
         drain = lam0 * (pause + mig) / slack
         benefit = (et_old - et_new) if np.isfinite(et_old) else float("inf")

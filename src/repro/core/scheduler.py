@@ -169,6 +169,12 @@ class DRSScheduler:
         self.cost_model = cost_model or RebalanceCostModel()
         self.cache = executable_cache
         self.scaling = scaling or ["replica"] * len(self.names)
+        if "keyed" in self.scaling:
+            raise ValueError(
+                "the live scheduler has no hot-key shares to price keyed "
+                "operators by (DESIGN.md §20); decide keyed graphs with "
+                "make_decide_jax or tick_batch over ControllerStatic.from_graphs"
+            )
         self.group_alpha = group_alpha or [0.0] * len(self.names)
         self.speed_factors = (
             None if speed_factors is None

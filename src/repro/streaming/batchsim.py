@@ -59,6 +59,7 @@ DES; DESIGN.md §17 quantifies the sojourn bounds per scenario family.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from typing import Sequence
 
 import numpy as np
 
@@ -67,6 +68,7 @@ __all__ = [
     "BatchSimResult",
     "BatchQueueSim",
     "composed_wait",
+    "refuse_keyed",
     "service_capacity",
     "stationary_wait",
     "window_step_fn",
@@ -79,6 +81,18 @@ __all__ = [
 # may stop at max(k) while the jit path always runs to the cap: both
 # orderings produce bit-identical lanes.
 STATIONARY_K_CAP = 512
+
+
+def refuse_keyed(names: Sequence[str]) -> None:
+    """Raise where keyed operators would enter the window simulation: its
+    queues are one per operator, served at the pooled ``k * mu``, and it
+    has no per-partition queues to hold a hot key (DESIGN.md §20)."""
+    if names:
+        raise ValueError(
+            f"keyed operators {sorted(set(names))}: the window simulation has "
+            "no per-partition queues (a hot key's partition saturates before "
+            "k * mu); simulate keyed graphs with the DES (streaming/des.py)"
+        )
 
 
 def service_capacity(k, mu, group, alpha, speed=None):

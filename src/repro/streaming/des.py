@@ -15,7 +15,12 @@ The simulator models exactly what the DSMS does:
 * each operator has one FIFO queue and ``k_i`` parallel servers with a
   configurable service-time distribution (exponential by default, but the
   paper stresses robustness to violations, so deterministic/uniform/
-  lognormal are supported);
+  lognormal are supported) — except a keyed operator (``scaling="keyed"``,
+  DESIGN.md §20), whose ``k_i`` servers are hash partitions of a keyed
+  stream, each with its own FIFO queue: a tuple carries the hot key with
+  probability ``hot_share`` and otherwise a fresh uniform key, and joins
+  partition ``key % k_i`` (the hot key is 0, so partition 0 is the hot
+  one); a rebalance re-hashes the queued tuples to the new partitions;
 * queues may be bounded (``SimConfig.queue_capacity``) with the same
   :class:`~repro.streaming.overload.OverloadPolicy` semantics as the live
   engine — block (backpressure via a pending line), shed-newest, or
@@ -284,6 +289,18 @@ class NetworkSimulator:
                 f"{self.cfg.queue_capacity}"
             )
         self.policy = OverloadPolicy.coerce(self.cfg.overload_policy)
+        # Keyed operators: per-partition queues of (t_enq, root_id, key)
+        # and per-partition busy flags, by operator index.
+        self._parts = {
+            i: [deque() for _ in range(max(int(self.k[i]), 1))]
+            for i, op in enumerate(topology.operators) if op.scaling == "keyed"
+        }
+        self._part_busy = {i: [False] * len(q) for i, q in self._parts.items()}
+        if self._parts and self.cfg.queue_capacity is not None:
+            raise ValueError(
+                "keyed operators are simulated with unbounded partition "
+                "queues only (queue_capacity=None)"
+            )
         self.rng = np.random.default_rng(self.cfg.seed)
         self._seq = itertools.count()
         self._events: list[tuple[float, int, int, tuple]] = []
@@ -346,6 +363,16 @@ class NetworkSimulator:
             self._op_arrivals_warm[i] += 1
         if self._probes is not None:
             self._probes[i].on_enqueue()
+        if i in self._parts:
+            op = self.top.operators[i]
+            hot = self.rng.random() < op.hot_share
+            key = 0 if hot else int(self.rng.integers(1, 1 << 31))
+            parts = self._parts[i]
+            self._roots[root_id].outstanding += 1
+            parts[key % len(parts)].append((self.now, root_id, key))
+            self._note_backlog(i)
+            self._try_start(i)
+            return True
         cap = self.cfg.queue_capacity
         q = self._queues[i]
         if cap is not None and (len(q) >= cap or self._pending[i]):
@@ -371,7 +398,10 @@ class NetworkSimulator:
         return True
 
     def _note_backlog(self, i: int) -> None:
-        backlog = len(self._queues[i]) + len(self._pending[i])
+        if i in self._parts:
+            backlog = sum(len(q) for q in self._parts[i])
+        else:
+            backlog = len(self._queues[i]) + len(self._pending[i])
         if backlog > self._op_max_backlog[i]:
             self._op_max_backlog[i] = backlog
 
@@ -407,23 +437,49 @@ class NetworkSimulator:
     def _try_start(self, i: int) -> None:
         if self.now < self._paused_until:
             return
+        if i in self._parts:
+            busy = self._part_busy[i]
+            for p, q in enumerate(self._parts[i]):
+                if q and not busy[p]:
+                    t_enq, root_id, _ = q.popleft()
+                    busy[p] = True
+                    self._serve(i, t_enq, root_id, p)
+            return
         q = self._queues[i]
         self._promote_pending(i)
         while self._busy[i] < self.k[i] and q:
             t_enq, root_id = q.popleft()
             self._promote_pending(i)  # a slot freed: unblock a producer
-            wait = self.now - t_enq
-            self._op_wait_sum[i] += wait
-            self._op_wait_n[i] += 1
-            st = self.services[i].sample(self.rng)
-            self._op_service_sum[i] += st
-            self._op_service_n[i] += 1
-            if self._probes is not None:
-                self._probes[i].on_processed(st)
-            self._busy[i] += 1
-            root = self._roots[root_id]
-            root.visit_time_sum += wait + st
-            self._push(self.now + st, _SERVICE_DONE, (i, root_id))
+            self._serve(i, t_enq, root_id)
+
+    def _serve(self, i: int, t_enq: float, root_id: int, *part: int) -> None:
+        """Start serving one dequeued tuple at operator i (on partition
+        ``part`` of a keyed operator)."""
+        wait = self.now - t_enq
+        self._op_wait_sum[i] += wait
+        self._op_wait_n[i] += 1
+        st = self.services[i].sample(self.rng)
+        self._op_service_sum[i] += st
+        self._op_service_n[i] += 1
+        if self._probes is not None:
+            self._probes[i].on_processed(st)
+        self._busy[i] += 1
+        root = self._roots[root_id]
+        root.visit_time_sum += wait + st
+        self._push(self.now + st, _SERVICE_DONE, (i, root_id, *part))
+
+    def _repartition(self, i: int, k: int) -> None:
+        """Re-hash a keyed operator's queued tuples over ``k`` partitions,
+        oldest first; tuples in service finish where they started."""
+        queued = sorted(
+            (item for q in self._parts[i] for item in q), key=lambda item: item[0]
+        )
+        busy = self._part_busy[i]
+        parts = [deque() for _ in range(max(k, 1))]
+        for item in queued:
+            parts[item[2] % len(parts)].append(item)
+        self._parts[i] = parts
+        self._part_busy[i] = busy[: len(parts)] + [False] * (len(parts) - len(busy))
 
     def _retire_root(self, root_id: int) -> None:
         """Outstanding count hit zero: record completion or shed."""
@@ -497,8 +553,10 @@ class NetworkSimulator:
                     self._admit(j, root_id)
                     self._finish_derived(root_id)  # wire leg done
             elif kind == _SERVICE_DONE:
-                i, root_id = payload
+                i, root_id, *part = payload
                 self._busy[i] -= 1
+                if part and part[0] < len(self._part_busy[i]):
+                    self._part_busy[i][part[0]] = False
                 self._route_downstream(i, root_id)
                 self._finish_derived(root_id)
                 self._try_start(i)
@@ -506,6 +564,8 @@ class NetworkSimulator:
                 if payload[0] == "rebalance":
                     _, k_new, pause = payload
                     self.k = k_new.copy()
+                    for i in self._parts:
+                        self._repartition(i, int(k_new[i]))
                     self._rebalances.append((self.now, k_new.copy(), pause))
                     if pause > 0:
                         self._paused_until = self.now + pause

@@ -31,7 +31,7 @@ from typing import Mapping, Sequence
 import numpy as np
 
 from ..api.graph import AppGraph, Edge, OpDef
-from .batchsim import BatchArrays
+from .batchsim import BatchArrays, refuse_keyed
 from .overload import OverloadPolicy
 
 __all__ = [
@@ -519,6 +519,7 @@ def pack_scenarios(
     """
     if not scenarios:
         raise ValueError("need at least one scenario")
+    refuse_keyed([op.name for s in scenarios for op in s.graph.ops if op.scaling == "keyed"])
     dts = {s.dt for s in scenarios}
     horizons = {s.horizon for s in scenarios}
     warmups = {s.warmup for s in scenarios}

@@ -61,6 +61,13 @@ CASES = {
     "gain_topr_lanes_B16384_J21": (
         gk.gain_topr_pallas, [((16384, 3, 21), F32), ((16384,), I32)],
     ),
+    # The NEXmark cell's dense decide: N = 7 operators, k_max 44.
+    "gain_topr_lanes_B16384_N7_J44": (
+        gk.gain_topr_pallas, [((16384, 7, 44), F32), ((16384,), I32)],
+    ),
+    "erlang_c_S114688_k44": (
+        functools.partial(ek.erlang_b_table_pallas, k_hi=44), [((16384 * 7,), F32)],
+    ),
     "decide_fused_B16384_k64": (
         functools.partial(dk.batch_decide_pallas, k_hi=K),
         [((B, N), F32)] * 6 + [((B,), I32)],
