@@ -1215,6 +1215,35 @@ def _decide_statics(static: ControllerStatic, params: ControllerParams) -> dict:
     return st
 
 
+def _masked_candidates(G, k_start, active):
+    """Program (4)'s candidate gains: ``G [..., N, K]`` with the entries
+    below each operator's minimal feasible ``k_start``, of inactive
+    operators and non-finite ones set to 0.
+
+    ``gain_topr`` depends on a row's positive gains, not on where they
+    sit in the row, so this selects exactly what the row shifted to start
+    at ``k_start`` would, without a gather along k (element-serial on the
+    TPU): one select that XLA fuses.
+    """
+    import jax.numpy as jnp
+
+    k_col = jnp.arange(G.shape[-1], dtype=k_start.dtype)
+    keep = (k_col >= k_start[..., None]) & active[..., None] & jnp.isfinite(G)
+    return jnp.where(keep, G, 0.0)
+
+
+def _column_select(T, k_vec):
+    """``T[..., k_vec]`` along the last axis (``k_vec`` clipped into it)
+    as a one-hot select summed over k: exact, since one entry is picked
+    and the rest are 0, and ``where`` (not a multiply) keeps an ``inf``
+    in another column from making a NaN."""
+    import jax.numpy as jnp
+
+    k_sel = jnp.clip(k_vec, 0, T.shape[-1] - 1)[..., None]
+    hit = jnp.arange(T.shape[-1], dtype=k_sel.dtype) == k_sel
+    return jnp.where(hit, T, 0.0).sum(axis=-1)
+
+
 def _make_decide_core(
     n: int,
     k_hi: int,
@@ -1308,8 +1337,8 @@ def _make_decide_core(
 
         def _et_of(per_op):
             # Shared pricing tail: both decide paths produce raw per-op
-            # T gathers and normalise them HERE with the same expressions,
-            # so fused-on/off E[T] parity reduces to the gathers.
+            # T values at k and normalise them HERE with the same
+            # expressions, so fused-on/off E[T] parity reduces to those.
             contrib = jnp.where(lam > 0, lam * per_op, 0.0)
             return contrib.sum(axis=-1) / jnp.maximum(lam0_total, 1e-300)
 
@@ -1354,27 +1383,16 @@ def _make_decide_core(
             # --- Program (4): masked top-R over the gain table ---------- #
             with stages.scope("candidates"):
                 budget = jnp.clip(k_max - floor_total, 0, None).astype(jnp.int32)
-                j = jnp.arange(k_hi, dtype=jnp.int32)
-                idx = k_start[..., None] + j[None, None, :]
-                cand = jnp.take_along_axis(G, jnp.clip(idx, 0, k_hi - 1), axis=-1)
-                cand = jnp.where(
-                    (idx < k_hi) & active[..., None] & jnp.isfinite(cand), cand, 0.0
-                )
+                cand = _masked_candidates(G, k_start, active)
             with stages.scope("topr"):
                 take = topr_ops.gain_topr(
                     cand, budget, interpret=interpret, force_kernel=force_kernel
                 )
                 k4 = k_start + take
 
-            def _gather(k_vec):
-                return jnp.take_along_axis(
-                    T, jnp.clip(k_vec, 0, k_hi).astype(jnp.int32)[..., None],
-                    axis=-1,
-                )[..., 0]
-
             with stages.scope("price"):
-                t_cur_op = _gather(k_cur)
-                t4_op = _gather(k4)
+                t_cur_op = _column_select(T, k_cur)
+                t4_op = _column_select(T, k4)
 
         with stages.scope("price"):
             et_cur = _et_of(t_cur_op)

@@ -1,13 +1,15 @@
 """Pure-jnp oracle for the batched top-R gain selection (DESIGN.md §14).
 
 Input is the Algorithm-1 candidate-gain tensor ``cand[b, i, j]`` — the
-marginal benefit of operator *i*'s *j*-th extra processor in scenario
-*b*, gathered from the PR-3 gain table starting at each operator's
-minimal feasible allocation (masked/invalid entries are 0).  Each
-scenario hands out ``budget[b]`` processors to the largest *positive*
-gains; because every row is non-increasing (convexity, paper Ineq. 5)
-the result equals the scalar greedy's argmax walk, with threshold ties
-resolved in operator-index order (`allocator.greedy_increments`'s rule).
+marginal benefits of operator *i*'s extra processors in scenario *b*
+(masked/invalid entries are 0).  Only a row's positive gains count, not
+where they sit in it: the decide passes the gain table masked in place
+below each operator's minimal feasible allocation.  Each scenario hands
+out ``budget[b]`` processors to the largest *positive* gains; because
+each row's positive gains are non-increasing along k (convexity, paper
+Ineq. 5) the result equals the scalar greedy's argmax walk, with
+threshold ties resolved in operator-index order
+(`allocator.greedy_increments`'s rule).
 
 Selection = one threshold: ``take[b, i] = #{j : cand[b,i,j] > theta_b}``
 with ``theta_b`` the budget-th largest positive gain, plus ties at

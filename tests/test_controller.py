@@ -326,6 +326,90 @@ def test_gain_topr_kernel_breaks_ties_in_row_order():
 
 
 # --------------------------------------------------------------------------- #
+# The decide's in-place candidates and one-hot price select vs the gathers
+# --------------------------------------------------------------------------- #
+def _gathered_candidates(G, k_start, active):
+    """The candidate rows as gathered before: operator i's row shifted to
+    start at its minimal feasible ``k_start``, padded past k_hi with 0."""
+    k_hi = G.shape[-1]
+    idx = k_start[..., None] + jnp.arange(k_hi, dtype=jnp.int32)
+    cand = jnp.take_along_axis(G, jnp.clip(idx, 0, k_hi - 1), axis=-1)
+    return jnp.where((idx < k_hi) & active[..., None] & jnp.isfinite(cand), cand, 0.0)
+
+
+def _gain_case(b, n, k_hi, seed):
+    """Gain tables with every edge the decide meets: ``inf`` below a
+    random first finite column and ``inf``/NaN scattered anywhere; gains
+    from {0, 0.5, 1, 1.5} (ties within and across rows) or normal, about
+    a third negative; ``k_start`` over [0, k_hi + 1]; inactive operators;
+    budgets 0, 1, inside the positive count, exactly it and above it."""
+    rng = np.random.default_rng(seed)
+    ties = rng.choice([0.0, 0.5, 1.0, 1.5], (b, n, k_hi))
+    mixed = rng.normal(0.5, 1.0, (b, n, k_hi))
+    G = np.where(rng.random((b, 1, 1)) < 0.5, ties, mixed)
+    k_start = rng.integers(0, k_hi + 2, (b, n)).astype(np.int32)
+    G[np.arange(k_hi) < k_start[..., None] - rng.integers(0, 2, (b, n, 1))] = np.inf
+    odd = rng.random((b, n, k_hi))
+    G[odd < 0.02] = np.inf
+    G[odd > 0.98] = np.nan
+    active = rng.random((b, n)) < 0.85
+    pos = (np.asarray(_gathered_candidates(G, k_start, active)) > 0).sum(axis=(1, 2))
+    pick = np.arange(b) % 5
+    budget = np.select([pick == 0, pick == 1, pick == 2, pick == 3],
+                       [0, 1, pos, pos + 3], rng.integers(1, pos + 2))
+    return (G.astype(np.float32), k_start, active, budget.astype(np.int32))
+
+
+@pytest.mark.parametrize("n,k_hi", [(3, 22), (7, 44)], ids=["vld", "nexmark"])
+@pytest.mark.parametrize("impl,b", [("ref", 96), ("pallas", 40), ("lanes", 200)])
+def test_masked_candidates_take_what_the_gather_took(impl, b, n, k_hi):
+    """Program (4) on G masked in place below k_start takes bitwise what it
+    took on the gathered rows, on the oracle and both kernel layouts
+    (interpret mode; B < 128 per scenario, B >= 128 lanes)."""
+    from repro.kernels.gain_topr import kernel, ref
+
+    G, k_start, active, budget = _gain_case(b, n, k_hi, seed=b + n)
+    old = _gathered_candidates(G, k_start, active)
+    new = ctl._masked_candidates(jnp.asarray(G), jnp.asarray(k_start), jnp.asarray(active))
+    # The same positive gains per row, in other places.
+    pos_sorted = lambda c: np.sort(np.where(np.asarray(c) > 0, np.asarray(c), 0.0), axis=-1)
+    np.testing.assert_array_equal(pos_sorted(new), pos_sorted(old))
+    if impl == "ref":
+        topr = ref.gain_topr
+    else:
+        assert (kernel._lane_tile(b, n, k_hi + (-k_hi) % 8) is not None) == (impl == "lanes")
+        topr = lambda c, bud: kernel.gain_topr_pallas(c, bud, interpret=True)
+    want = np.asarray(topr(old, budget))
+    np.testing.assert_array_equal(np.asarray(topr(new, budget)), want)
+    assert (want.sum(axis=-1) == np.minimum(budget, (np.asarray(old) > 0).sum(axis=(1, 2)))).all()
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["f32", "f64"])
+def test_column_select_is_the_gather_bitwise(x64):
+    """The price stage's one-hot select equals ``take_along_axis`` bit for
+    bit: leading ``inf`` columns (below the least stable k), ``inf`` and
+    NaN elsewhere, k over [0, k_hi] and clipped from outside it."""
+    rng = np.random.default_rng(3)
+    b, n, k_hi = 64, 7, 44
+    T = rng.gamma(2.0, 1e-3, (b, n, k_hi + 1))
+    T[np.arange(k_hi + 1) < rng.integers(0, k_hi + 2, (b, n, 1))] = np.inf
+    T[rng.random(T.shape) < 0.02] = np.nan
+    k_vec = rng.integers(-2, k_hi + 4, (b, n)).astype(np.int32)
+    with jax.enable_x64(x64):
+        Tj = jnp.asarray(T, dtype=jnp.float64 if x64 else jnp.float32)
+        want = jnp.take_along_axis(Tj, jnp.clip(k_vec, 0, k_hi)[..., None], axis=-1)[..., 0]
+        uint = np.uint64 if x64 else np.uint32
+        # Op by op too: under jit XLA may turn a multiply by a 0/1 mask
+        # into the select it stands for, and so hide a NaN.
+        for select in (ctl._column_select, jax.jit(ctl._column_select)):
+            got = select(Tj, jnp.asarray(k_vec))
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(np.asarray(got).view(uint),
+                                          np.asarray(want).view(uint))
+        assert np.isinf(np.asarray(want)).any() and np.isnan(np.asarray(want)).any()
+
+
+# --------------------------------------------------------------------------- #
 # MeasurementBatch plumbing
 # --------------------------------------------------------------------------- #
 def test_stack_snapshots_roundtrip():

@@ -9,12 +9,15 @@ let go of them.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import repro.core.controller as ctl
+from repro.api import AppGraph, Edge, OpDef
 from repro.api.session import ScenarioRunner
 from repro.core import stages
 from repro.streaming.scenarios import fpd_scenario, vld_scenario
@@ -74,6 +77,23 @@ def _only_program():
     return program, shapes
 
 
+def _nexmark_shaped(b):
+    """B lanes of the NEXmark cell's decide shape: N = 7, four keyed
+    operators, k_max 44."""
+    keyed = (False, True, True, False, True, True, False)
+    ops = [OpDef(f"o{i}", mu=5000.0, scaling="keyed" if kd else "replica",
+                 hot_share=0.5 if kd else None) for i, kd in enumerate(keyed)]
+    graph = AppGraph(ops, [Edge(f"o{i}", f"o{i + 1}") for i in range(6)], {"o0": 10000.0})
+    static = ctl.ControllerStatic.from_graphs([graph] * b)
+    full = lambda v: np.full(b, v)
+    params = ctl.ControllerParams(
+        t_max=full(np.nan), k_max=np.full(b, 44, np.int64), headroom=full(1.1),
+        scale_in_hysteresis=full(0.8), min_improvement=full(0.05),
+        horizon_seconds=full(300.0), allocator=("table",) * b,
+    )
+    return static, params
+
+
 def test_stage_of_takes_the_innermost_scope():
     path = "jit(decide)/drs.compact/cond/branch_1_fun/drs.topr/pallas_call"
     assert stages.stage_of(path) == "topr"
@@ -111,6 +131,30 @@ def test_dense_decide_covers_the_decide_stages(vld, fresh):
     decide(*_inputs(vld.static))
     table = stages.op_stages()
     assert _staged(table) == set(stages.DECIDE)
+
+
+@pytest.mark.parametrize("shape", ["vld", "nexmark"])
+def test_candidates_and_price_gather_nothing(vld, fresh, shape):
+    """The candidates are G masked in place and the price a one-hot select
+    (DESIGN.md §19): no gather along k in either stage, and each still
+    owns a compiled instruction."""
+    if shape == "vld":
+        static, params = vld.static, vld._params()
+        args = _inputs(static)
+    else:
+        static, params = _nexmark_shaped(B)
+        rng = np.random.default_rng(1)
+        lam = rng.uniform(1000.0, 9000.0, (B, 7)).astype(np.float32)
+        args = (lam, np.full((B, 7), 5000.0, np.float32), np.zeros((B, 7), np.float32),
+                lam[:, 0].copy(), np.full((B, 7), 3, np.int32))
+    assert (static.n, int(params.k_max.max())) == {"vld": (3, 22), "nexmark": (7, 44)}[shape]
+    ctl.make_decide_jax(static, params)(*args)
+    program, shapes = _only_program()
+    text = program.lower(*shapes).compile().as_text()
+    owner = stages.instruction_stages(text)
+    gathers = re.findall(r"^\s*(?:ROOT\s+)?%?([^\s=]+) = [^\n]* gather\(", text, re.MULTILINE)
+    assert not {owner[g] for g in gathers} & {"candidates", "price"}
+    assert {"candidates", "price"} <= set(owner.values())
 
 
 def test_compacted_decide_adds_compact(vld, fresh):
