@@ -11,7 +11,7 @@ arrays.  Rows:
 * ``decide_scalar_seconds_B{B}`` — wall-clock for one control tick driven
   through B per-scenario ``DRSScheduler.tick_from`` calls (the PR-4
   ScenarioRunner structure);
-* ``decide_fused_seconds_B{B}`` — the same B decisions through the jit
+* ``decide_jit_seconds_B{B}`` — the same B decisions through the jit
   ``make_decide_jax`` program (post-compile, per-call mean);
 * ``speedup_fused_vs_scalar_B64`` — the acceptance gate: >= 20x at B=64;
 * ``fused_loop_ticks_per_second_B{B}`` — whole fused simulate -> measure
@@ -139,7 +139,7 @@ def run(smoke: bool = False) -> list[tuple[str, float, str]]:
         out[1].block_until_ready()
         t_fused.append(time.perf_counter() - t0)
     fused_s = float(np.median(t_fused))
-    rows.append((f"decide_fused_seconds_B{b}", fused_s,
+    rows.append((f"decide_jit_seconds_B{b}", fused_s,
                  "s per tick, one jit decide over the [B, N] stack"))
     rows.append((
         f"speedup_fused_vs_scalar_B{b}",

@@ -120,109 +120,6 @@ def run(smoke: bool = False) -> list[tuple[str, float, str]]:
         f"us interpret; keeps {hidden_bytes/2**20:.1f} MiB hidden in VMEM",
     ))
 
-    # fused batch-decide: offered load -> Program-4 allocation in one pass
-    from repro.kernels.decide_fused import ops as ddops, ref as ddref
-
-    rng = np.random.default_rng(0)
-    db, dn, dk_hi = 16, 8, 512
-    lam = np.abs(rng.normal(3.0, 1.5, (db, dn))).astype(np.float32)
-    mu = (np.abs(rng.normal(5.0, 1.0, (db, dn))) + 1.0).astype(np.float32)
-    group = np.zeros((db, dn), dtype=bool)
-    alpha = np.zeros((db, dn), dtype=np.float32)
-    active = np.ones((db, dn), dtype=bool)
-    k_cur = rng.integers(1, 6, (db, dn)).astype(np.int32)
-    k_max = np.full(db, 40, dtype=np.int32)
-    d_args = (lam, mu)
-    d_kw = dict(group=group, alpha=alpha, active=active, k_cur=k_cur, k_max=k_max)
-
-    # interpret-parity gate at a cheap shape: the Pallas kernel's integer
-    # decision surface must equal the oracle's exactly
-    pb, pk = (2, 32) if smoke else (4, 64)
-    p_kw = {k: v[:pb] for k, v in d_kw.items() if k != "k_max"}
-    p_kw["k_max"] = k_max[:pb]
-    got = ddops.batch_decide(lam[:pb], mu[:pb], k_hi=pk, j_cap=40,
-                             force_kernel=True, interpret=True, **p_kw)
-    want = ddref.batch_decide(lam[:pb], mu[:pb], k_hi=pk, j_cap=40, **p_kw)
-    parity = float(
-        bool(np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
-             and np.array_equal(np.asarray(got[1]), np.asarray(want[1]))
-             and np.allclose(got[2], want[2], rtol=1e-4, atol=1e-6)
-             and np.allclose(got[3], want[3], rtol=1e-4, atol=1e-6))
-    )
-    rows.append((
-        "decide_fused_interpret_parity", parity,
-        f"kernel == jnp oracle at ({pb},{dn},k_hi={pk}) (1.0 = match); "
-        "k4/k_start exact, T gathers at kernel tolerance",
-    ))
-
-    # compiled CPU-jit decide latency at the ISSUE shape: two-pass
-    # (full-window sort selection, unroll=1) vs fused (j_cap window +
-    # threshold bisection + tuned unroll) — the 1.6 ms/tick gate.  Not
-    # reduced under --smoke: each call is ~ms and fewer reps makes the
-    # gate label flap on dispatch jitter.
-    reps = 10
-    twopass = jax.jit(lambda l, m: ddref.batch_decide(
-        l, m, k_hi=dk_hi, j_cap=None, unroll=1, **d_kw))
-    fused = jax.jit(lambda l, m: ddref.batch_decide(
-        l, m, k_hi=dk_hi, j_cap=48, unroll=ddops.DEFAULT_UNROLL, **d_kw))
-    t_two, out_two = timeit(twopass, *d_args, repeat=reps)
-    t_fus, out_fus = timeit(fused, *d_args, repeat=reps)
-    for a, b in zip(out_two[:2], out_fus[:2]):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    rows.append((
-        "decide_twopass_ms", t_two * 1e3,
-        f"ms/tick two-pass erlang_c->gain_topr at B={db} N={dn} K={dk_hi} (cpu jit)",
-    ))
-    rows.append((
-        "decide_fused_ms", t_fus * 1e3,
-        f"ms/tick fused decide, same shape, j_cap=48 unroll="
-        f"{ddops.DEFAULT_UNROLL} ({t_two / t_fus:.1f}x, gate < 1.6 ms: "
-        f"{'PASS' if t_fus * 1e3 < 1.6 else 'FAIL'})",
-    ))
-
-    # HBM traffic the fusion deletes: two-pass round-trips the sojourn
-    # table T [B,N,K+1] and the gain table G [B,N,K] through memory
-    # (write + read each); fused keeps both VMEM-resident
-    saved = 2 * 4 * (db * dn * (dk_hi + 1) + db * dn * dk_hi)
-    rows.append((
-        "decide_fused_hbm_bytes_saved", float(saved),
-        f"bytes/decide not round-tripped at B={db} N={dn} K={dk_hi} "
-        f"({saved/2**20:.2f} MiB: T and G stay VMEM-resident)",
-    ))
-
-    # block-shape tuning hook: persist the Erlang scan unroll sweep so
-    # DEFAULT_UNROLL stays auditable per host
-    a_sweep = jnp.asarray(np.abs(rng.normal(4.0, 3.0, db * dn)), dtype=jnp.float32)
-    sweep = (1, ddops.DEFAULT_UNROLL) if smoke else ddops.UNROLL_SWEEP
-    best, timings = ddops.autotune_unroll(
-        a_sweep, k_hi=dk_hi, sweep=sweep, reps=1 if smoke else 5
-    )
-    for u, sec in sorted(timings.items()):
-        rows.append((
-            f"erlang_unroll_{u}", sec * 1e6,
-            f"us erlang_b_table [{db * dn},{dk_hi}] scan unroll={u}",
-        ))
-    rows.append((
-        "erlang_unroll_best", float(best),
-        f"autotuned scan unroll (DEFAULT_UNROLL={ddops.DEFAULT_UNROLL}; "
-        "bitwise-safe, perf-only)",
-    ))
-
-    # compiled-backend rows only where a real accelerator is attached —
-    # interpret wall-clock is not TPU performance (see module docstring)
-    if jax.default_backend() in ("tpu", "gpu"):
-        t_comp, out_comp = timeit(
-            lambda l, m: ddops.batch_decide(
-                l, m, k_hi=dk_hi, j_cap=48, force_kernel=True, **d_kw),
-            *d_args, repeat=reps,
-        )
-        np.testing.assert_array_equal(
-            np.asarray(out_comp[0]), np.asarray(out_fus[0])
-        )
-        rows.append((
-            "decide_fused_compiled_ms", t_comp * 1e3,
-            f"ms/tick compiled pallas_call on {jax.default_backend()}",
-        ))
     return rows
 
 

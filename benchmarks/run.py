@@ -22,9 +22,6 @@ Every run also persists its rows to a ``BENCH_<name>.json`` artifact at
 the repo root (schema ``{bench, rows, smoke, timestamp}``); the CI
 bench-smoke job uploads them, so the perf trajectory accumulates per PR
 instead of evaporating with the job log.
-
-Roofline tables (EXPERIMENTS §Dry-run/§Roofline) are produced separately
-by ``python -m benchmarks.roofline`` from the dry-run records.
 """
 
 from __future__ import annotations

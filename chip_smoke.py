@@ -8,8 +8,8 @@ fleet size DRS users run, and checks what comes out:
    chip, at the loop's widths;
 2. loop: ``ScenarioRunner(backend="jax")`` over B = 16384 lanes (a
    64-scenario zoo tiled 256 times; 8 ticks of 100 steps at dt = 0.05 s,
-   the first tick warmup) with the two-pass decide, with
-   ``fused_decide=True`` and with ``compact=True``.
+   the first tick warmup) with the dense decide and with
+   ``compact=True``.
 
 With ``--mesh 4`` it runs only the sharded paths, on four chips: the
 fused loop over ``fleet_mesh(4)`` at B = 65536 against the same program
@@ -110,40 +110,7 @@ def _exact(name, got, want) -> None:
         raise AssertionError(f"{name}: {bad} entries differ from the oracle")
 
 
-def decide_case(base: list, tiles: int, n: int, rng):
-    """Decide-kernel inputs at fleet width: the zoo's solved mean rates,
-    each tiled lane scaled by its own load factor."""
-    from repro.core.controller import ControllerStatic
-    from repro.streaming.scenarios import pack_allocations
-
-    st = ControllerStatic.from_graphs([s.graph for s in base])
-    b0 = len(base)
-    lam = np.zeros((b0, n))
-    mu = np.ones((b0, n))
-    for i, s in enumerate(base):
-        top = s.mean_topology()
-        lam[i, : top.n] = top.arrival_rates
-        mu[i, : top.n] = [op.mu for op in top.operators]
-
-    def pad(x, fill=0):
-        out = np.full((b0, n), fill, dtype=x.dtype)
-        out[:, : x.shape[1]] = x
-        return np.tile(out, (tiles, 1))
-
-    k_cur = pack_allocations(base, [s.plan_k0() for s in base])
-    load = rng.uniform(0.6, 1.4, (b0 * tiles, 1))
-    return dict(
-        lam=(np.tile(lam, (tiles, 1)) * load).astype(np.float32),
-        mu_eff=np.tile(mu, (tiles, 1)).astype(np.float32),
-        group=pad(st.group).astype(np.float32),
-        alpha=pad(st.alpha).astype(np.float32),
-        active=pad(st.active).astype(np.float32),
-        k_cur=pad(k_cur).astype(np.int32),
-        k_max=np.tile([s.k_max for s in base], tiles).astype(np.int32),
-    )
-
-
-def check_kernels(base: list, *, b: int, n: int) -> None:
+def check_kernels(*, b: int, n: int) -> None:
     """Each kernel at the loop's widths against its oracle, both on the
     chip."""
     import functools
@@ -151,7 +118,6 @@ def check_kernels(base: list, *, b: int, n: int) -> None:
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels.decide_fused import kernel as dk, ref as dref
     from repro.kernels.erlang_c import kernel as ek, ref as eref
     from repro.kernels.gain_topr import kernel as gk, ref as gref
     from repro.kernels.l2_match import kernel as lk, ref as lref
@@ -183,21 +149,6 @@ def check_kernels(base: list, *, b: int, n: int) -> None:
     budget = jnp.asarray(rng.integers(0, n * K_HI // 2, b), dtype=jnp.int32)
     got = gk.gain_topr_pallas(cand, budget)
     _exact("gain_topr take", got, jax.jit(gref.gain_topr)(cand, budget))
-
-    log(f"kernel decide_fused, B = {b}, N = {n}, k_hi = {K_HI}")
-    case = decide_case(base, b // len(base), n, rng)
-    kw = dict(k_hi=K_HI, j_cap=K_HI)
-    got = dk.batch_decide_pallas(*case.values(), **kw)
-    want = jax.jit(
-        lambda c: dref.batch_decide(
-            c["lam"], c["mu_eff"], group=c["group"] > 0, alpha=c["alpha"],
-            active=c["active"] > 0, k_cur=c["k_cur"], k_max=c["k_max"], **kw,
-        )
-    )(case)
-    _exact("decide_fused k_start", got[1], want[1])
-    _exact("decide_fused k4", got[0], want[0])
-    _close("decide_fused t_cur", got[2], want[2], KERNEL_RTOL)
-    _close("decide_fused t4", got[3], want[3], KERNEL_RTOL)
 
     # Small-integer descriptors keep every distance an exact integer on
     # both paths, so the counts compare exactly whatever the matmul
@@ -343,10 +294,8 @@ def check_loop(base: list, tiles: int, device: str, clock: CompileClock) -> None
     from repro.api.session import ScenarioRunner
 
     scens = base * tiles
-    dense, dense_out = run_loop("two-pass", scens, device, clock)
-    check_invariants("two-pass", dense, dense_out, tiles)
-    fused, fused_out = run_loop("fused_decide", scens, device, clock, fused_decide=True)
-    check_invariants("fused_decide", fused, fused_out, tiles)
+    dense, dense_out = run_loop("dense", scens, device, clock)
+    check_invariants("dense", dense, dense_out, tiles)
     compact, compact_out = run_loop("compact", scens, device, clock, compact=True)
     check_invariants("compact", compact, compact_out, tiles)
     for key in ("codes", "k", "applied", "k_final"):
@@ -357,8 +306,6 @@ def check_loop(base: list, tiles: int, device: str, clock: CompileClock) -> None
     twin = ScenarioRunner(base, tick_interval=TICK, backend="numpy")
     twin.run()
     ref = _surface(dense, len(base))
-    log(f"reported: base lanes where fused_decide differs from two-pass: "
-        f"{_differing(_surface(fused, len(base)), ref)} of {len(base)}")
     log(f"reported: base lanes where {device} differs from the float64 numpy "
         f"twin: {_differing(_surface(twin, len(base)), ref)} of {len(base)}")
 
@@ -440,7 +387,7 @@ def main(argv=None) -> int:
             return 1
         check_mesh(base, args.mesh, MESH_TILES, kind, clock)
     else:
-        check_kernels(base, b=BASE * TILES, n=8)
+        check_kernels(b=BASE * TILES, n=8)
         check_loop(base, TILES, kind, clock)
     log(f"all phases passed in {time.perf_counter() - t0!r} s")
     print(json.dumps({"ok": True, "device": device}))

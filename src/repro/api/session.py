@@ -881,7 +881,6 @@ class ScenarioRunner:
         interpret: bool = False,
         force_kernel: bool = False,
         fused: bool | None = None,
-        fused_decide: bool = False,
         proactive=None,
         mesh=None,
         compact=None,
@@ -895,10 +894,6 @@ class ScenarioRunner:
         self.backend = backend
         self.interpret = interpret
         self.force_kernel = force_kernel
-        # The decide-dispatch knob (SchedulerConfig.fused_decide): route
-        # the jit decide through kernels/decide_fused — note this is
-        # orthogonal to `fused` below, which fuses the *loop* over ticks.
-        self.fused_decide = bool(fused_decide)
         # Device mesh for the fused loop (DESIGN.md §16): shard the batch
         # axis across devices.  Only the fused path consumes it — the
         # window-at-a-time twin is a numpy debugging surface.
@@ -966,7 +961,6 @@ class ScenarioRunner:
                     t_max=s.t_max,
                     tick_interval=self.tick_interval,
                     allocator=s.allocator,
-                    fused_decide=self.fused_decide,
                 )
                 for s, neg in zip(self.scenarios, self.negotiators)
             ],

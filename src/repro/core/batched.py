@@ -274,7 +274,6 @@ def sojourn_table_jax(
     hot=None,
     interpret: bool = False,
     force_kernel: bool = False,
-    unroll: int = 1,
 ):
     """jnp ``[N, k_hi+1]`` sojourn table (the numpy path's jit-able twin).
 
@@ -283,9 +282,7 @@ def sojourn_table_jax(
     to exercise the Pallas kernel itself on CPU (``interpret`` alone does
     not switch the dispatch — repo kernel idiom, see kernels/__init__.py).
     Group-scaled operators use the M/M/1 closed form and are merged in
-    with ``jnp.where`` so the whole function stays traceable.  ``unroll``
-    tunes the reference scan's unroll factor — bitwise-safe, so callers
-    may autotune it freely (kernels/decide_fused does).  ``hot`` (``[N]``,
+    with ``jnp.where`` so the whole function stays traceable.  ``hot`` (``[N]``,
     NaN where an operator is not keyed) merges in the keyed closed form
     (DESIGN.md §20); ``None`` traces none of it.
     """
@@ -310,7 +307,6 @@ def sojourn_table_jax(
     a_rep = lam / mu
     btab = _erlang_ops.erlang_b_table(
         a_rep, k_hi=k_hi, interpret=interpret, force_kernel=force_kernel,
-        unroll=unroll,
     ).T.astype(dtype)  # [N, K+1]
     kk = ks[None, :]
     c = kk * btab / (kk - a_rep[:, None] * (1.0 - btab))

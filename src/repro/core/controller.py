@@ -195,21 +195,19 @@ class ControllerParams:
     min_improvement: np.ndarray  # [B]
     horizon_seconds: np.ndarray  # [B]
     allocator: tuple  # [B] "table" | "heap"
-    fused_decide: bool = False  # dispatch the decide to kernels/decide_fused
+    # Accepted for callers that still pass it; only False is valid.
+    fused_decide: bool = False
+
+    def __post_init__(self):
+        if self.fused_decide:
+            raise ValueError(
+                "fused_decide=True: the one-pass decide was removed; every "
+                "decide runs the two-pass dataflow (leave fused_decide False)"
+            )
 
     @classmethod
     def stack(cls, configs: Sequence, k_max: Sequence[int]) -> "ControllerParams":
         """From B SchedulerConfig-likes + resolved per-scenario budgets."""
-        per_lane = [bool(getattr(c, "fused_decide", False)) for c in configs]
-        flags = set(per_lane)
-        if len(flags) > 1:
-            on = [i for i, f in enumerate(per_lane) if f]
-            off = [i for i, f in enumerate(per_lane) if not f]
-            raise ValueError(
-                "fused_decide must agree across a stacked batch (one jit "
-                "program serves every scenario lane); scenario indices "
-                f"{on} set fused_decide=True while {off} leave it False"
-            )
         return cls(
             t_max=np.array(
                 [np.nan if c.t_max is None else float(c.t_max) for c in configs]
@@ -220,7 +218,6 @@ class ControllerParams:
             min_improvement=np.array([c.min_improvement for c in configs]),
             horizon_seconds=np.array([c.horizon_seconds for c in configs]),
             allocator=tuple(c.allocator for c in configs),
-            fused_decide=flags.pop() if flags else False,
         )
 
 
@@ -274,7 +271,6 @@ def pad_params(params: ControllerParams, b_total: int) -> ControllerParams:
         min_improvement=np.concatenate([params.min_improvement, np.full(pad, np.inf)]),
         horizon_seconds=np.concatenate([params.horizon_seconds, np.zeros(pad)]),
         allocator=params.allocator + ("table",) * pad,
-        fused_decide=params.fused_decide,
     )
 
 
@@ -1181,13 +1177,6 @@ def _topr_ops():
     return topr_ops
 
 
-def _decide_fused_ops():
-    """The ``kernels/decide_fused`` dispatch module (same lazy idiom)."""
-    from ..kernels.decide_fused import ops as fused_ops
-
-    return fused_ops
-
-
 def _decide_statics(static: ControllerStatic, params: ControllerParams) -> dict:
     """The decide's per-lane array inputs as one ``[B, ...]``-leading dict.
 
@@ -1250,8 +1239,6 @@ def _make_decide_core(
     pause: float,
     interpret: bool,
     force_kernel: bool,
-    fused: bool = False,
-    j_cap: int | None = None,
 ):
     """The decide body as a pure function of (statics dict, measurements).
 
@@ -1259,21 +1246,11 @@ def _make_decide_core(
     on whatever batch extent its inputs carry — the full ``B`` under plain
     jit, or one device's ``B/D`` shard under ``shard_map`` (every op is
     per-lane, so shard results are bit-identical to the unsharded run).
-
-    ``fused=True`` dispatches the model chain (sojourn table ->
-    Algorithm-1 gains -> Program-4 top-R -> E[T] gathers) to
-    ``kernels/decide_fused`` as ONE pass: the Pallas kernel on TPU /
-    ``force_kernel``, otherwise its jnp oracle — which is composed from
-    the identical expressions this two-pass body runs, so CPU decisions
-    are bit-for-bit the same either way (tier-1 enforced).  ``j_cap``
-    truncates the per-lane candidate window (exact while the budget
-    stays <= ``j_cap``; callers pass the fleet-wide max budget).
     """
     import jax
     import jax.numpy as jnp
 
     topr_ops = _topr_ops()
-    fused_ops = _decide_fused_ops() if fused else None
     from .batched import sojourn_table_jax, solve_traffic_batch_jax
 
     def decide(st, lam_hat, mu_hat, drop_hat, lam0_hat, k_current):
@@ -1335,66 +1312,51 @@ def _make_decide_core(
             lam = jnp.where(jnp.isfinite(lam) & (lam >= 0), lam, 0.0)
             lam0_total = lam0.sum(axis=-1)
 
-        def _et_of(per_op):
-            # Shared pricing tail: both decide paths produce raw per-op
-            # T values at k and normalise them HERE with the same
-            # expressions, so fused-on/off E[T] parity reduces to those.
-            contrib = jnp.where(lam > 0, lam * per_op, 0.0)
-            return contrib.sum(axis=-1) / jnp.maximum(lam0_total, 1e-300)
-
-        if fused:
-            # --- ONE fused pass: table -> gains -> Program (4) -> E[T] -- #
-            k4, k_start, t_cur_op, t4_op = fused_ops.batch_decide(
-                lam, mu_eff, group=group, alpha=alpha, active=active,
-                k_cur=k_cur, k_max=k_max, k_hi=k_hi, j_cap=j_cap,
+        # --- one table pass: E[T_i](k) and Algorithm-1 gains ------------ #
+        with stages.scope("table"):
+            T = sojourn_table_jax(
+                lam.reshape(-1), mu_eff.reshape(-1), k_hi=k_hi,
+                group=group.reshape(-1), alpha=alpha.reshape(-1),
+                min_k=jnp.ones(b * n, dtype=jnp.int32),
+                hot=jnp.where(keyed, st["hot"], jnp.nan).reshape(-1)
+                if keyed is not None else None,
                 interpret=interpret, force_kernel=force_kernel,
-            )
+            ).reshape(b, n, k_hi + 1)
+            G = lam[..., None] * (T[..., :-1] - T[..., 1:])
+            G = jnp.where(jnp.isfinite(T[..., :-1]), G, jnp.inf)
+
+            # Minimal feasible allocation = first finite table column.
+            finite = jnp.isfinite(T)
+            has_finite = finite.any(axis=-1)
+            first = jnp.argmax(finite, axis=-1).astype(jnp.int32)
+            k_start = jnp.where(active, jnp.where(has_finite, first, k_hi + 1), 0)
             floor_total = k_start.sum(axis=-1)
             infeasible = solve_bad | (floor_total > k_max)
-        else:
-            # --- one table pass: E[T_i](k) and Algorithm-1 gains -------- #
-            with stages.scope("table"):
-                T = sojourn_table_jax(
-                    lam.reshape(-1), mu_eff.reshape(-1), k_hi=k_hi,
-                    group=group.reshape(-1), alpha=alpha.reshape(-1),
-                    min_k=jnp.ones(b * n, dtype=jnp.int32),
-                    hot=jnp.where(keyed, st["hot"], jnp.nan).reshape(-1)
-                    if keyed is not None else None,
-                    interpret=interpret, force_kernel=force_kernel,
-                ).reshape(b, n, k_hi + 1)
-                G = lam[..., None] * (T[..., :-1] - T[..., 1:])
-                G = jnp.where(jnp.isfinite(T[..., :-1]), G, jnp.inf)
+            if keyed is not None:
+                # Keyed operators whose floor the hot partition set:
+                # above the pooled M/M/k floor floor(lam/mu) + 1.
+                pooled = jnp.floor(lam / mu_eff) + 1.0
+                hot_floor = (
+                    keyed & active & (k_start.astype(dtype) > pooled)
+                ).sum(axis=-1, dtype=jnp.int32)
 
-                # Minimal feasible allocation = first finite table column.
-                finite = jnp.isfinite(T)
-                has_finite = finite.any(axis=-1)
-                first = jnp.argmax(finite, axis=-1).astype(jnp.int32)
-                k_start = jnp.where(active, jnp.where(has_finite, first, k_hi + 1), 0)
-                floor_total = k_start.sum(axis=-1)
-                infeasible = solve_bad | (floor_total > k_max)
-                if keyed is not None:
-                    # Keyed operators whose floor the hot partition set:
-                    # above the pooled M/M/k floor floor(lam/mu) + 1.
-                    pooled = jnp.floor(lam / mu_eff) + 1.0
-                    hot_floor = (
-                        keyed & active & (k_start.astype(dtype) > pooled)
-                    ).sum(axis=-1, dtype=jnp.int32)
-
-            # --- Program (4): masked top-R over the gain table ---------- #
-            with stages.scope("candidates"):
-                budget = jnp.clip(k_max - floor_total, 0, None).astype(jnp.int32)
-                cand = _masked_candidates(G, k_start, active)
-            with stages.scope("topr"):
-                take = topr_ops.gain_topr(
-                    cand, budget, interpret=interpret, force_kernel=force_kernel
-                )
-                k4 = k_start + take
-
-            with stages.scope("price"):
-                t_cur_op = _column_select(T, k_cur)
-                t4_op = _column_select(T, k4)
+        # --- Program (4): masked top-R over the gain table -------------- #
+        with stages.scope("candidates"):
+            budget = jnp.clip(k_max - floor_total, 0, None).astype(jnp.int32)
+            cand = _masked_candidates(G, k_start, active)
+        with stages.scope("topr"):
+            take = topr_ops.gain_topr(
+                cand, budget, interpret=interpret, force_kernel=force_kernel
+            )
+            k4 = k_start + take
 
         with stages.scope("price"):
+            def _et_of(per_op):
+                contrib = jnp.where(lam > 0, lam * per_op, 0.0)
+                return contrib.sum(axis=-1) / jnp.maximum(lam0_total, 1e-300)
+
+            t_cur_op = _column_select(T, k_cur)
+            t4_op = _column_select(T, k4)
             et_cur = _et_of(t_cur_op)
             et4 = _et_of(t4_op)
 
@@ -1464,7 +1426,6 @@ def make_decide_jax(
     pause_seconds: float | None = None,
     interpret: bool = False,
     force_kernel: bool = False,
-    fused: bool | None = None,
     mesh=None,
     compact=None,
 ):
@@ -1495,12 +1456,6 @@ def make_decide_jax(
     non-finite or negative solved rates (no eigvalue check inside jit),
     and Program (6) sizing is skipped (it only feeds negotiator leases).
 
-    ``fused`` routes the model chain through ``kernels/decide_fused``
-    (one pass, DESIGN.md §12); ``None`` reads ``params.fused_decide``
-    (the SchedulerConfig knob, default off).  On CPU the fused oracle is
-    bit-exact with the two-pass path, so flipping the knob never changes
-    a decision — only the dispatch.
-
     ``compact`` (``True`` or a :class:`CompactionConfig`) returns the
     trigger-gated sparse decide instead (DESIGN.md §18): signature
     ``decide(lam_hat, mu_hat, drop_hat, lam0_hat, k_current, cache) ->
@@ -1515,8 +1470,7 @@ def make_decide_jax(
     hot partition and adds a sixth output, ``hot_floor [B]`` int32: the
     keyed operators per lane whose least stable allocation lies above the
     pooled M/M/k floor ``floor(lam/mu) + 1``.  Graphs without one trace
-    none of it.  ``kernels/decide_fused`` has no keyed table: asking for
-    it with a keyed operator raises ``ValueError``.
+    none of it.
     """
     import jax
     import jax.numpy as jnp
@@ -1527,141 +1481,69 @@ def make_decide_jax(
         RebalanceCostModel().pause_cache_miss if pause_seconds is None
         else pause_seconds
     )
-    if fused is None:
-        fused = bool(getattr(params, "fused_decide", False))
     keyed = static.keyed
-    if fused and keyed:
-        raise ValueError(
-            "kernels/decide_fused has no keyed-operator table (DESIGN.md §20): "
-            "decide graphs with scaling='keyed' with fused=False"
-        )
-    # Exactness bound for the fused path's candidate-window truncation:
-    # every scenario's Program-4 budget is <= its k_max, so the fleet max
-    # caps the window (ref.py proof) — static because params is static.
-    j_cap = min(k_hi, max(int(params.k_max.max()), 1))
-    core = _make_decide_core(
-        n, k_hi, pause, interpret, force_kernel, fused=fused, j_cap=j_cap
-    )
+    body = _make_decide_core(n, k_hi, pause, interpret, force_kernel)
 
-    if mesh is None:
-        st = {k: jnp.asarray(v) for k, v in _decide_statics(static, params).items()}
-
-        if compact:
-            core_c = _make_compact_decide(core, b, _resolve_ladder(compact, b))
-            jitted = stages.recorded(jax.jit(
-                lambda lam, mu, drop, lam0, k, cache: core_c(
-                    st, lam, mu, drop, lam0, k, cache
-                )
-            ))
-
-            def decide_compact(lam_hat, mu_hat, drop_hat, lam0_hat, k_current,
-                               cache):
-                return jitted(
-                    lam_hat, mu_hat, drop_hat, lam0_hat, k_current, cache
-                )
-
-            decide_compact.init_cache = lambda dtype=None: init_decide_cache(
-                b, n, dtype=dtype, keyed=keyed
-            )
-            return decide_compact
-
-        def decide(lam_hat, mu_hat, drop_hat, lam0_hat, k_current):
-            return core(st, lam_hat, mu_hat, drop_hat, lam0_hat, k_current)
-
-        return stages.recorded(jax.jit(decide))
-
-    from jax.sharding import PartitionSpec as P
-
-    axis, n_shards = _mesh_axis(mesh)
+    # The one composition: core, compacted when asked, run per lane shard
+    # under a mesh.  B is padded to a multiple of the device count.
+    axis, n_shards = (None, 1) if mesh is None else _mesh_axis(mesh)
     b_pad = _padded_batch(b, n_shards)
+    pad = b_pad - b
     st_np = _decide_statics(pad_static(static, b_pad), pad_params(params, b_pad))
     st = {k: jnp.asarray(v) for k, v in st_np.items()}
-    st_specs = {
-        k: P(axis, *((None,) * (v.ndim - 1))) for k, v in st_np.items()
-    }
-    row = P(axis, None)
-    lane = P(axis)
-    pad = b_pad - b
-    out_specs = (lane, row, lane, lane, lane) + ((lane,) if keyed else ())
-
     if compact:
-        # Per-shard compaction: each device runs the trigger scan and the
-        # bucketed dispatch on its own lane shard — no cross-device
-        # gather, at the cost of load imbalance (see bucket_ladder).
+        # Under a mesh each device compacts its own lane shard: no
+        # cross-device gather, at the cost of load imbalance (see
+        # bucket_ladder).
         b_shard = b_pad // n_shards
-        core_c = _make_compact_decide(
-            core, b_shard, _resolve_ladder(compact, b_shard)
-        )
-        cache_specs = DecideCache(
-            ok=lane, lam=row, mu=row, drop=row, lam0=lane, k=row,
-            code=lane, k_next=row, et_cur=lane, et_target=lane, applied=lane,
-            hot_floor=lane if keyed else None,
-        )
-        sharded_c = jax.shard_map(
-            core_c,
-            mesh=mesh,
-            in_specs=(st_specs, row, row, row, lane, row, cache_specs),
-            out_specs=(out_specs, lane, cache_specs),
+        body = _make_compact_decide(body, b_shard, _resolve_ladder(compact, b_shard))
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+
+        row, lane = P(axis, None), P(axis)
+        st_specs = {
+            k: P(axis, *((None,) * (v.ndim - 1))) for k, v in st_np.items()
+        }
+        in_specs = (st_specs, row, row, row, lane, row)
+        out_specs = (lane, row, lane, lane, lane) + ((lane,) if keyed else ())
+        if compact:
+            cache_specs = DecideCache(
+                ok=lane, lam=row, mu=row, drop=row, lam0=lane, k=row,
+                code=lane, k_next=row, et_cur=lane, et_target=lane,
+                applied=lane, hot_floor=lane if keyed else None,
+            )
+            in_specs += (cache_specs,)
+            out_specs = (out_specs, lane, cache_specs)
+        body = jax.shard_map(
+            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
 
-        def decide_padded(lam_hat, mu_hat, drop_hat, lam0_hat, k_current,
-                          cache):
-            if pad:
-                dtype = lam_hat.dtype
-                lam_hat = jnp.concatenate([lam_hat, jnp.zeros((pad, n), dtype)])
-                mu_hat = jnp.concatenate([mu_hat, jnp.ones((pad, n), dtype)])
-                drop_hat = jnp.concatenate(
-                    [drop_hat, jnp.zeros((pad, n), dtype)]
-                )
-                lam0_hat = jnp.concatenate([lam0_hat, jnp.zeros(pad, dtype)])
-                k_current = jnp.concatenate(
-                    [k_current, jnp.zeros((pad, n), k_current.dtype)]
-                )
-            out, repriced, cache = sharded_c(
-                st, lam_hat, mu_hat, drop_hat, lam0_hat, k_current, cache
-            )
-            if pad:
-                out = tuple(o[:b] for o in out)
-                repriced = repriced[:b]
-            return out, repriced, cache
+    def decide(lam_hat, mu_hat, drop_hat, lam0_hat, k_current, *cache):
+        if not pad:
+            return body(st, lam_hat, mu_hat, drop_hat, lam0_hat, k_current, *cache)
+        # Inert lanes in, real lanes out; a compact cache keeps the padded
+        # extent (it is a shard_map operand).
+        dtype = lam_hat.dtype
+        lam_hat = jnp.concatenate([lam_hat, jnp.zeros((pad, n), dtype)])
+        mu_hat = jnp.concatenate([mu_hat, jnp.ones((pad, n), dtype)])
+        drop_hat = jnp.concatenate([drop_hat, jnp.zeros((pad, n), dtype)])
+        lam0_hat = jnp.concatenate([lam0_hat, jnp.zeros(pad, dtype)])
+        k_current = jnp.concatenate(
+            [k_current, jnp.zeros((pad, n), k_current.dtype)]
+        )
+        res = body(st, lam_hat, mu_hat, drop_hat, lam0_hat, k_current, *cache)
+        if compact:
+            out, repriced, cache = res
+            return tuple(o[:b] for o in out), repriced[:b], cache
+        return tuple(o[:b] for o in res)
 
-        jitted = stages.recorded(jax.jit(decide_padded))
-
-        def decide_compact(lam_hat, mu_hat, drop_hat, lam0_hat, k_current,
-                           cache):
-            return jitted(lam_hat, mu_hat, drop_hat, lam0_hat, k_current, cache)
-
-        # The cache lives at the PADDED extent (it is a shard_map operand).
-        decide_compact.init_cache = lambda dtype=None: init_decide_cache(
+    decide = stages.recorded(jax.jit(decide))
+    if compact:
+        decide.init_cache = lambda dtype=None: init_decide_cache(
             b_pad, n, dtype=dtype, keyed=keyed
         )
-        return decide_compact
-
-    sharded = jax.shard_map(
-        core,
-        mesh=mesh,
-        in_specs=(st_specs, row, row, row, lane, row),
-        out_specs=out_specs,
-        check_vma=False,
-    )
-
-    def decide(lam_hat, mu_hat, drop_hat, lam0_hat, k_current):
-        if pad:
-            dtype = lam_hat.dtype
-            lam_hat = jnp.concatenate([lam_hat, jnp.zeros((pad, n), dtype)])
-            mu_hat = jnp.concatenate([mu_hat, jnp.ones((pad, n), dtype)])
-            drop_hat = jnp.concatenate([drop_hat, jnp.zeros((pad, n), dtype)])
-            lam0_hat = jnp.concatenate([lam0_hat, jnp.zeros(pad, dtype)])
-            k_current = jnp.concatenate(
-                [k_current, jnp.zeros((pad, n), k_current.dtype)]
-            )
-        out = sharded(st, lam_hat, mu_hat, drop_hat, lam0_hat, k_current)
-        if pad:
-            out = tuple(o[:b] for o in out)
-        return out
-
-    return stages.recorded(jax.jit(decide))
+    return decide
 
 
 class ControllerState(NamedTuple):
@@ -1747,7 +1629,6 @@ def make_fused_loop(
     warmup_seconds: float | None = None,
     interpret: bool = False,
     force_kernel: bool = False,
-    fused: bool | None = None,
     proactive=None,
     mesh=None,
     compact=None,
@@ -1826,9 +1707,6 @@ def make_fused_loop(
     steps = arrays.steps
     n_ticks = steps // steps_per_tick
     k_hi_res = int(k_hi if k_hi is not None else max(int(params.k_max.max()), 1))
-    if fused is None:
-        fused = bool(getattr(params, "fused_decide", False))
-    j_cap = min(k_hi_res, max(int(params.k_max.max()), 1))
     if compact:
         compact_cfg = (
             compact if isinstance(compact, CompactionConfig) else CompactionConfig()
@@ -1846,7 +1724,7 @@ def make_fused_loop(
 
     decide_core = _make_decide_core(
         n, k_hi_res, float(RebalanceCostModel().pause_cache_miss),
-        interpret, force_kernel, fused=fused, j_cap=j_cap,
+        interpret, force_kernel,
     )
     window = window_step_fn(interpret=interpret, force_kernel=force_kernel)
     # Every [B, ...]-leading array rides in one of two dicts so the mesh
@@ -1956,43 +1834,6 @@ def make_fused_loop(
             )
             mpc_ladder = _resolve_ladder(compact_cfg, bb)
 
-        if proactive is not None and fused:
-            # MPC candidate allocator through the SAME fused dispatch:
-            # the planner hands us the candidate budgets as absolute
-            # totals (already clipped to [floor_total, k_max]), so the
-            # fused pass's internal budget = clip(k_max - floor, 0)
-            # equals the planner's `extra` exactly — the tables agree
-            # bitwise (sojourn_table_arrays mirrors sojourn_table_jax),
-            # hence so do k_start and the selected increments.
-            # Parameterized over the statics so the compacted MPC branch
-            # can rebuild it from gathered (compacted-width) operands.
-            def mpc_alloc_of(mu_eff_x, group_x, alpha_x, active_x):
-                def mpc_alloc(lam_m, budgets_m):
-                    bx = active_x.shape[0]
-                    m = lam_m.shape[0]
-                    r = m // bx
-
-                    def rep(x):
-                        return jnp.broadcast_to(
-                            x[:, None, :], (bx, r, x.shape[-1])
-                        ).reshape(m, x.shape[-1])
-
-                    k4_m, _, _, _ = _decide_fused_ops().batch_decide(
-                        lam_m, rep(mu_eff_x), group=rep(group_x),
-                        alpha=rep(alpha_x), active=rep(active_x),
-                        k_cur=jnp.zeros(lam_m.shape, dtype=jnp.int32),
-                        k_max=budgets_m, k_hi=k_hi_res, j_cap=j_cap,
-                        interpret=interpret, force_kernel=force_kernel,
-                    )
-                    return k4_m
-
-                return mpc_alloc
-
-            mpc_alloc = mpc_alloc_of(mu_eff, group, alpha, active)
-        else:
-            mpc_alloc_of = None
-            mpc_alloc = None
-
         def tick_fn(carry, t_idx):
             if compact_cfg is not None:
                 carry, dcache = carry[:-1], carry[-1]
@@ -2093,12 +1934,7 @@ def make_fused_loop(
                             speed=sim_d["speed"][g], active=active[g],
                             src_mask=st_d["src"][g],
                             cap_queue=sim_d["cap_queue"][g], t_max=t_max[g],
-                            k_max=st_d["k_max"][g],
-                            alloc=None if mpc_alloc_of is None
-                            else mpc_alloc_of(
-                                mu_eff[g], group[g], alpha[g], active[g]
-                            ),
-                            **plan_kw,
+                            k_max=st_d["k_max"][g], **plan_kw,
                         )
                         return kp, ok, eh, ep
 
@@ -2113,8 +1949,7 @@ def make_fused_loop(
                         lam_pred, q1, k, mu=mu, group=st_d["group"],
                         alpha=alpha, speed=sim_d["speed"], active=active,
                         src_mask=st_d["src"], cap_queue=sim_d["cap_queue"],
-                        t_max=t_max, k_max=st_d["k_max"], alloc=mpc_alloc,
-                        **plan_kw,
+                        t_max=t_max, k_max=st_d["k_max"], **plan_kw,
                     )
                 use = conf & any_ok & complete & ~hot & jnp.isfinite(t_max)
                 changed = use & (

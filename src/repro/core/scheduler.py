@@ -86,11 +86,6 @@ class SchedulerConfig:
     # "heap" keeps the scalar heap greedy (PR-1 behaviour, used as a
     # cross-check in tests and benchmarks).
     allocator: str = "table"
-    # Dispatch the jit decide's model chain to kernels/decide_fused as ONE
-    # pass (Pallas on TPU; on CPU the fused oracle is bit-exact with the
-    # two-pass erlang_c -> gain_topr path, which stays the parity oracle).
-    # Default off until the parity gate has run on the target backend.
-    fused_decide: bool = False
 
 
 # Backwards-compatible alias: the solver pairs now live with the rest of

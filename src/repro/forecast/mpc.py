@@ -259,7 +259,6 @@ def mpc_plan(
     k_hi: int,
     xp=np,
     topr=None,
-    alloc=None,
 ):
     """One MPC planning pass over the forecast horizon.
 
@@ -270,12 +269,7 @@ def mpc_plan(
     [B]`` (inf = no constraint), ``k_max [B]`` budgets, ``span`` seconds
     per control tick.  ``topr(cand [M,N,J], budget [M]) -> take [M,N]``
     is the top-R gain selection (defaults to the numpy twin; the jit
-    path passes ``kernels/gain_topr``).  ``alloc(lam_m [M, N],
-    budgets_m [M]) -> k_alloc [M, N]``, when given, replaces the whole
-    floor + top-R block with one fused allocator call per candidate
-    budget (``kernels/decide_fused`` — budgets are absolute totals, so
-    the hook recomputes the same floor internally and spends
-    ``budget - floor``; bit-identical to the ``topr`` route).
+    path passes ``kernels/gain_topr``).
 
     Returns ``(k_plan [B, N] int, any_ok [B] bool, et_hold [B],
     et_plan [B], need [B] int)``: the committed allocation, whether any
@@ -346,17 +340,12 @@ def mpc_plan(
     step = int(cfg.neighbor)
     budgets = xp.stack([need, need - step, need + step], axis=-1)  # [B, 3]
     budgets = xp.clip(budgets, floor_total[:, None], k_max[:, None])
-    if alloc is not None:
-        lam_rep = xp.broadcast_to(lam_peak[:, None, :], (b, 3, n)).reshape(b * 3, n)
-        k_alloc = alloc(lam_rep, budgets.reshape(b * 3)).reshape(b, 3, n)
-        k_alloc = k_alloc.astype(xp.int32)
-    else:
-        extra = xp.clip(budgets - floor_total[:, None], 0, None).astype(xp.int32)
-        cand_rep = xp.broadcast_to(cand[:, None, :, :], (b, 3, n, k_hi)).reshape(
-            b * 3, n, k_hi
-        )
-        take = topr(cand_rep, extra.reshape(b * 3))
-        k_alloc = k_start[:, None, :] + take.reshape(b, 3, n).astype(xp.int32)
+    extra = xp.clip(budgets - floor_total[:, None], 0, None).astype(xp.int32)
+    cand_rep = xp.broadcast_to(cand[:, None, :, :], (b, 3, n, k_hi)).reshape(
+        b * 3, n, k_hi
+    )
+    take = topr(cand_rep, extra.reshape(b * 3))
+    k_alloc = k_start[:, None, :] + take.reshape(b, 3, n).astype(xp.int32)
     k_alloc = xp.where(active[:, None, :], k_alloc, 0)
     k_hold = xp.where(active, k_cur, 0).astype(xp.int32)[:, None, :]
     k_cand = xp.concatenate([k_hold, k_alloc], axis=1)  # [B, C=4, N]

@@ -15,13 +15,8 @@ def erlang_b_table(
     k_hi: int,
     interpret: bool = False,
     force_kernel: bool = False,
-    unroll: int = 1,
 ):
-    """[S] offered loads -> [k_hi+1, S] Erlang-B blocking table.
-
-    ``unroll`` tunes the reference scan's unroll factor (bitwise-safe);
-    the Pallas kernel iterates in-core and ignores it.
-    """
+    """[S] offered loads -> [k_hi+1, S] Erlang-B blocking table."""
     if force_kernel or jax.default_backend() == "tpu":
         return _kernel.erlang_b_table_pallas(a, k_hi=k_hi, interpret=interpret)
-    return _ref.erlang_b_table(a, k_hi=k_hi, unroll=unroll)
+    return _ref.erlang_b_table(a, k_hi=k_hi)

@@ -8,14 +8,9 @@ import jax.numpy as jnp
 __all__ = ["erlang_b_table"]
 
 
-def erlang_b_table(a: jnp.ndarray, *, k_hi: int, unroll: int = 1) -> jnp.ndarray:
+def erlang_b_table(a: jnp.ndarray, *, k_hi: int) -> jnp.ndarray:
     """[S] offered loads -> [k_hi+1, S] table; dtype follows the input
-    (float64 under enable_x64, else float32).
-
-    ``unroll`` is forwarded to ``lax.scan``: it restructures the loop
-    without reassociating any per-lane float op, so the table is bitwise
-    identical for every value (asserted in tests/test_kernels_all.py).
-    """
+    (float64 under enable_x64, else float32)."""
     a = jnp.asarray(a)
     b0 = jnp.ones_like(a)
 
@@ -24,5 +19,5 @@ def erlang_b_table(a: jnp.ndarray, *, k_hi: int, unroll: int = 1) -> jnp.ndarray
         return b, b
 
     js = jnp.arange(1, k_hi + 1, dtype=a.dtype)
-    _, rows = jax.lax.scan(step, b0, js, unroll=max(int(unroll), 1))
+    _, rows = jax.lax.scan(step, b0, js)
     return jnp.concatenate([b0[None, :], rows], axis=0)

@@ -658,7 +658,6 @@ def control_trace(
     proactive=None,
     backend: str = "numpy",
     interpret: bool = False,
-    fused_decide: bool = False,
     compact=None,
 ) -> dict:
     """JSON-able decision trace of the full control loop over ``scenarios``
@@ -677,10 +676,8 @@ def control_trace(
 
     ``backend="jax"`` replays the same trace through the fused jit loop
     under enable_x64 (bit-identical to the twin for non-negotiated
-    scenarios); ``fused_decide`` flips the one-pass
-    ``kernels/decide_fused`` dispatch inside it, and ``interpret`` runs
-    any Pallas dispatch in interpret mode — together the golden replay
-    surface for the fused-decide knob (tests/test_golden_traces.py).
+    scenarios); ``interpret`` runs any Pallas dispatch in interpret mode
+    (tests/test_golden_traces.py).
     ``compact`` (True or a :class:`~repro.core.controller.CompactionConfig`)
     turns on the trigger-gated sparse decide (DESIGN.md §18); compaction
     is output-invisible, so every golden must replay bit-identically with
@@ -691,8 +688,7 @@ def control_trace(
     def _run():
         runner = ScenarioRunner(
             scenarios, tick_interval=tick_interval, backend=backend,
-            proactive=proactive, interpret=interpret,
-            fused_decide=fused_decide, compact=compact,
+            proactive=proactive, interpret=interpret, compact=compact,
         )
         return runner.run()
 

@@ -68,10 +68,9 @@ def entries():
         ("fpd", fpd_scenario(), None, 10.0),
         ("vld_proactive", flash_vld, mpc, 10.0),
         # Static-budget VLD: jit-eligible (no negotiator), so this one
-        # fixture is ALSO replayed through the fused jax loop with the
-        # kernels/decide_fused knob on (tests/test_golden_traces.py) —
-        # the knob-on decision surface must match this twin-generated
-        # trace bit-for-bit.
+        # fixture is ALSO replayed through the fused jax loop
+        # (tests/test_golden_traces.py) — the jit decision surface must
+        # match this twin-generated trace bit-for-bit.
         ("vld_fused", vld_scenario(name="vld_fused", negotiated=False), None, 10.0),
         ("soak", soak, None, 120.0),
     ]

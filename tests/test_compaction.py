@@ -24,6 +24,7 @@ sharded/unsharded comparison already tolerates (tests/test_mesh_control.py).
 * every committed golden fixture replayed with compaction on.
 """
 
+import dataclasses
 import json
 import math
 import pathlib
@@ -345,14 +346,11 @@ def test_golden_fused_replays_through_compacted_jit_loop():
 # --------------------------------------------------------------------------- #
 # Satellites
 # --------------------------------------------------------------------------- #
-def test_stack_mixed_fused_decide_error_names_indices():
-    configs = [
-        SchedulerConfig(k_max=4, fused_decide=(i in (1, 3))) for i in range(5)
-    ]
-    with pytest.raises(ValueError) as ei:
-        ctl.ControllerParams.stack(configs, [4] * 5)
-    msg = str(ei.value)
-    assert "[1, 3]" in msg and "[0, 2, 4]" in msg
+def test_controller_params_refuse_the_one_pass_decide():
+    params = ctl.ControllerParams.stack([SchedulerConfig(k_max=4)] * 2, [4, 4])
+    assert params.fused_decide is False
+    with pytest.raises(ValueError, match="one-pass decide was removed"):
+        dataclasses.replace(params, fused_decide=True)
 
 
 def test_bench_provenance_fields():

@@ -409,6 +409,84 @@ def test_column_select_is_the_gather_bitwise(x64):
         assert np.isinf(np.asarray(want)).any() and np.isnan(np.asarray(want)).any()
 
 
+def _zoo_decide_case(seeds, extra_budget):
+    """Zoo-derived decide inputs: random AppGraph topologies (chains,
+    splits, joins, leaking loops) stacked into one padded [B, N] batch,
+    with a few stable lanes flipped to gang ("group") scaling so both
+    sojourn branches appear."""
+    from repro.streaming.scenarios import random_appgraph
+
+    tops = [random_appgraph(s).topology() for s in seeds]
+    b, n = len(tops), max(t.n for t in tops)
+    lam = np.zeros((b, n))
+    mu = np.ones((b, n))
+    group = np.zeros((b, n), dtype=bool)
+    alpha = np.zeros((b, n))
+    active = np.zeros((b, n), dtype=bool)
+    rng = np.random.default_rng(seeds[0])
+    for i, top in enumerate(tops):
+        lam[i, : top.n] = top.arrival_rates
+        mu[i, : top.n] = [op.mu for op in top.operators]
+        active[i, : top.n] = top.arrival_rates > 0
+        for lane in range(top.n):
+            # group scaling saturates at mu/alpha; only flip lanes with
+            # plenty of headroom so every lane stays feasible
+            if rng.random() < 0.3 and lam[i, lane] < 0.2 * mu[i, lane] / 0.02:
+                group[i, lane] = True
+                alpha[i, lane] = 0.02
+    k_cur = rng.integers(0, 6, size=(b, n)).astype(np.int32)
+    floor = np.where(active, np.floor(lam / mu) + 1, 0).sum(axis=1)
+    k_max = (floor + extra_budget).astype(np.int32)
+    return lam, mu, group, alpha, active, k_cur, k_max
+
+
+@pytest.mark.parametrize("seeds,extra_budget", [((0, 1, 2, 3), 64), ((4, 5), 1024)],
+                         ids=["k64", "k1024"])
+def test_decide_matches_float64_twin_on_zoo(seeds, extra_budget):
+    """The jit decide equals the float64 numpy twin (``tick_batch``) on
+    random AppGraph fleets, budgets 64 and 1024 above the floor, gang
+    lanes included: codes, allocations and the applied mask exactly."""
+    import dataclasses
+
+    from repro.streaming.scenarios import random_appgraph
+
+    lam, mu, group, alpha, _, k_cur, k_max = _zoo_decide_case(seeds, extra_budget)
+    graphs = [random_appgraph(s) for s in seeds]
+    static = dataclasses.replace(
+        ctl.ControllerStatic.from_graphs(graphs), group=group, alpha=alpha
+    )
+    b = static.batch
+    full = lambda v: np.full(b, v)
+    params = ctl.ControllerParams(
+        t_max=full(np.nan), k_max=k_max.astype(np.int64), headroom=full(1.1),
+        scale_in_hysteresis=full(0.8), min_improvement=full(0.05),
+        horizon_seconds=full(300.0), allocator=("table",) * b,
+    )
+    lam0 = np.array([g.topology().lam0_total for g in graphs])
+    drop = np.zeros_like(lam)
+    k = np.where(static.active, k_cur, 0).astype(np.int64)
+    codes = set()
+    # Two ticks: the second from the allocation the first applied.
+    for _ in range(2):
+        rows = ctl.tick_batch(
+            MeasurementBatch(lam, mu, lam0, np.full(b, np.nan), 0.0, drop), k, static,
+            params,
+        ).rows
+        with jax.enable_x64(True):
+            code, k_next, _, _, applied = map(
+                np.asarray, ctl.make_decide_jax(static, params)(lam, mu, drop, lam0, k)
+            )
+        np.testing.assert_array_equal(code, [r.code for r in rows])
+        want = np.zeros_like(k)
+        for bi, r in enumerate(rows):  # twin rows are each lane's own width
+            want[bi, : len(r.k_next)] = r.k_next
+        np.testing.assert_array_equal(k_next, want)
+        np.testing.assert_array_equal(applied, [r.applied for r in rows])
+        codes |= set(code.tolist())
+        k = want
+    assert len(codes) > 1, codes
+
+
 # --------------------------------------------------------------------------- #
 # MeasurementBatch plumbing
 # --------------------------------------------------------------------------- #

@@ -67,22 +67,20 @@ def test_golden_trace_replays(name):
         assert got[metric] == pytest.approx(want[metric], rel=1e-6, abs=1e-9), metric
 
 
-@pytest.mark.parametrize("fused_decide", [True, False])
-def test_golden_trace_replays_through_fused_jit_loop(fused_decide):
+def test_golden_trace_replays_through_fused_jit_loop():
     """The jit-eligible golden fixture replays bit-for-bit through the
-    fused jax loop — with the ``kernels/decide_fused`` knob ON (interpret
-    mode) and off.  The fixture itself is twin-generated, so this pins
-    twin == jit two-pass == jit fused on the decision surface."""
+    fused jax loop (Pallas dispatch in interpret mode).  The fixture
+    itself is twin-generated, so this pins twin == jit on the decision
+    surface."""
     path = GOLDEN / "vld_fused_control_trace.json"
     want = json.loads(path.read_text())["scenarios"]["vld_fused"]
     scenario, proactive = ENTRIES["vld_fused"]
     got = control_trace(
         [scenario], tick_interval=10.0, proactive=proactive,
-        backend="jax", interpret=True, fused_decide=fused_decide,
+        backend="jax", interpret=True,
     )["scenarios"]["vld_fused"]
     assert got["actions"] == want["actions"], (
-        "fused-knob replay drifted from the committed golden decision "
-        "sequence — the fused dispatch must be bit-exact on CPU"
+        "jit replay drifted from the committed golden decision sequence"
     )
     assert got["allocations"] == want["allocations"]
     assert got["provisioned_total"] == want["provisioned_total"]

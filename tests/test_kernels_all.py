@@ -9,9 +9,7 @@ try:
 except ImportError:  # no dev deps installed — deterministic fallback sweep
     from _hypothesis_fallback import given, settings, strategies as st
 
-from repro.kernels.decide_fused import ops as ddops, ref as ddref
 from repro.kernels.decode_attention import kernel as dk, ref as dref
-from repro.kernels.erlang_c import ref as eref
 from repro.kernels.flash_attention import kernel as fk, ref as fref
 from repro.kernels.gain_topr import kernel as tk, ref as topr_ref
 from repro.kernels.rwkv6_scan import kernel as rk, ref as rref
@@ -210,148 +208,8 @@ def test_ssd_scan_kernel_matches_oracle():
 
 
 # --------------------------------------------------------------------- #
-# decide_fused: one pass from offered load to the Program-4 allocation
+# gain_topr: Program (4)'s top-R selection
 # --------------------------------------------------------------------- #
-def _zoo_decide_case(seeds, extra_budget=24):
-    """Zoo-derived decide inputs: random AppGraph topologies (chains,
-    splits, joins, leaking loops) stacked into one padded [B, N] batch,
-    with a few stable lanes flipped to gang ("group") scaling so both
-    sojourn branches appear."""
-    from repro.streaming.scenarios import random_appgraph
-
-    tops = [random_appgraph(s).topology() for s in seeds]
-    b, n = len(tops), max(t.n for t in tops)
-    lam = np.zeros((b, n))
-    mu = np.ones((b, n))
-    group = np.zeros((b, n), dtype=bool)
-    alpha = np.zeros((b, n))
-    active = np.zeros((b, n), dtype=bool)
-    rng = np.random.default_rng(seeds[0])
-    for i, top in enumerate(tops):
-        lam[i, : top.n] = top.arrival_rates
-        mu[i, : top.n] = [op.mu for op in top.operators]
-        active[i, : top.n] = top.arrival_rates > 0
-        for lane in range(top.n):
-            # group scaling saturates at mu/alpha; only flip lanes with
-            # plenty of headroom so every lane stays feasible
-            if rng.random() < 0.3 and lam[i, lane] < 0.2 * mu[i, lane] / 0.02:
-                group[i, lane] = True
-                alpha[i, lane] = 0.02
-    k_cur = rng.integers(0, 6, size=(b, n)).astype(np.int32)
-    floor = np.where(active, np.floor(lam / mu) + 1, 0).sum(axis=1)
-    k_max = (floor + extra_budget).astype(np.int32)
-    return lam, mu, group, alpha, active, k_cur, k_max
-
-
-def _decide(fn, case, k_hi, **kw):
-    lam, mu, group, alpha, active, k_cur, k_max = case
-    return fn(lam, mu, group=group, alpha=alpha, active=active,
-              k_cur=k_cur, k_max=k_max, k_hi=k_hi, **kw)
-
-
-@pytest.mark.parametrize("seeds,k_hi", [((0, 1, 2, 3), 64), ((4, 5), 1024)])
-def test_decide_fused_oracle_matches_numpy_twin_x64(seeds, k_hi):
-    """jnp oracle == float64 numpy twin bit-for-bit under x64, across the
-    zoo and up to K=1024."""
-    case = _zoo_decide_case(seeds)
-    with jax.enable_x64(True):
-        got = _decide(ddref.batch_decide, case, k_hi)
-    want = _decide(ddref.batch_decide_np, case, k_hi)
-    for name, g, w in zip(("k4", "k_start", "t_cur", "t4"), got, want):
-        np.testing.assert_array_equal(np.asarray(g), w, err_msg=name)
-
-
-@pytest.mark.parametrize("x64", [False, True])
-def test_decide_fused_matches_two_pass_decide_bitwise(x64):
-    """The dispatch contract: make_decide_jax with the fused knob on must
-    reproduce the two-pass erlang_c->gain_topr decide bit-for-bit on CPU,
-    in both float32 and float64."""
-    import contextlib
-
-    import repro.core.controller as ctl
-    from repro.api.session import ScenarioRunner
-    from repro.streaming.scenarios import scenario_matrix
-
-    scens = [
-        s.with_(negotiated=False)
-        for s in scenario_matrix(4, seed=17, horizon=20.0, warmup=5.0, dt=0.05)
-    ]
-    with jax.enable_x64(True) if x64 else contextlib.nullcontext():
-        r = ScenarioRunner(scens, tick_interval=5.0, backend="jax")
-        b, n = len(scens), r.static.n
-        rng = np.random.default_rng(5)
-        lam = np.abs(rng.normal(2.0, 0.6, (b, n)))
-        mu = np.abs(rng.normal(6.0, 0.5, (b, n))) + 1.0
-        drop = np.zeros((b, n))
-        lam0 = np.abs(rng.normal(2.0, 0.5, b))
-        k = np.where(r.static.active, 2, 0).astype(np.int64)
-        two = ctl.make_decide_jax(r.static, r._params(), fused=False)(
-            lam, mu, drop, lam0, k
-        )
-        one = ctl.make_decide_jax(r.static, r._params(), fused=True)(
-            lam, mu, drop, lam0, k
-        )
-    for name, a, f in zip(("code", "k_next", "et_cur", "et_target", "applied"),
-                          two, one):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(f), err_msg=name)
-
-
-@pytest.mark.parametrize("seeds,k_hi,j_cap", [
-    ((6, 7, 8), 64, None),     # B=3, zoo N is no tile multiple
-    ((9, 10), 200, 48),        # truncated window through the kernel too
-])
-def test_decide_fused_kernel_interpret_matches_oracle(seeds, k_hi, j_cap):
-    """Pallas kernel (interpret) vs the float32 oracle: the integer
-    decision surface is exact; T gathers compare with the kernel-tier
-    tolerance (loop vs vectorized FMA contraction)."""
-    case = _zoo_decide_case(seeds)
-    f32 = tuple(
-        np.asarray(a, dtype=np.float32) if a.dtype.kind == "f" else a for a in case
-    )
-    got = _decide(ddops.batch_decide, f32, k_hi, j_cap=j_cap,
-                  force_kernel=True, interpret=True)
-    want = _decide(ddref.batch_decide, f32, k_hi, j_cap=j_cap)
-    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]), err_msg="k4")
-    np.testing.assert_array_equal(
-        np.asarray(got[1]), np.asarray(want[1]), err_msg="k_start"
-    )
-    for name, g, w in zip(("t_cur", "t4"), got[2:], want[2:]):
-        np.testing.assert_allclose(
-            np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-6, err_msg=name
-        )
-
-
-def test_decide_fused_jcap_truncation_is_exact():
-    """Window truncation to j_cap >= budget is provably lossless: gains
-    are non-increasing per lane, so the selected set (ties included) is
-    identical to the full-window selection — bitwise, not approximately."""
-    case = _zoo_decide_case((11, 12, 13))
-    k_max = case[-1]
-    with jax.enable_x64(True):
-        full = _decide(ddref.batch_decide, case, 128, j_cap=None)
-        capped = _decide(ddref.batch_decide, case, 128, j_cap=int(k_max.max()))
-    for name, a, b in zip(("k4", "k_start", "t_cur", "t4"), full, capped):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
-
-
-def test_decide_fused_erlang_unroll_is_bitwise_safe():
-    """The scan-unroll perf knob must not change a single bit: unrolling
-    only restructures the loop, every lane still runs the same float ops
-    in the same order."""
-    a = np.abs(np.random.default_rng(3).normal(4.0, 3.0, 96))
-    base = np.asarray(eref.erlang_b_table(a, k_hi=512, unroll=1))
-    for u in (2, 4, 8):
-        np.testing.assert_array_equal(
-            np.asarray(eref.erlang_b_table(a, k_hi=512, unroll=u)), base,
-            err_msg=f"unroll={u}",
-        )
-    case = _zoo_decide_case((14, 15))
-    u1 = _decide(ddref.batch_decide, case, 64, unroll=1)
-    u4 = _decide(ddref.batch_decide, case, 64, unroll=4)
-    for name, x, y in zip(("k4", "k_start", "t_cur", "t4"), u1, u4):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y), err_msg=name)
-
-
 def test_gain_topr_padded_lanes_contribute_zero():
     """The hoisted pad-shape contract: tile padding rides through as zero
     gains, so hand-padding the candidate tile changes nothing — real
@@ -434,22 +292,6 @@ def test_gain_topr_layout_follows_shape(shape, layout):
 # `-m tpu`, compiling on an accelerator and falling back to the
 # force_kernel+interpret path on CPU so the lane never goes dark.
 # --------------------------------------------------------------------- #
-@pytest.mark.tpu
-def test_decide_fused_backend_lane():
-    interpret = jax.default_backend() != "tpu"
-    case = _zoo_decide_case((20, 21, 22))
-    f32 = tuple(
-        np.asarray(a, dtype=np.float32) if a.dtype.kind == "f" else a for a in case
-    )
-    got = _decide(ddops.batch_decide, f32, 128, j_cap=48,
-                  force_kernel=True, interpret=interpret)
-    want = _decide(ddref.batch_decide, f32, 128, j_cap=48)
-    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
-    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
-    for g, w in zip(got[2:], want[2:]):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-3, atol=1e-5)
-
-
 @pytest.mark.tpu
 def test_gain_topr_backend_lane():
     interpret = jax.default_backend() != "tpu"

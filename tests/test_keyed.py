@@ -246,8 +246,6 @@ def test_paths_without_partitions_refuse_keyed_graphs():
     from repro.streaming.scenarios import Scenario, pack_scenarios, vld_scenario
 
     graphs, static, params = _fleet(0)
-    with pytest.raises(ValueError, match="decide_fused has no keyed"):
-        ctl.make_decide_jax(static, params, fused=True)
     arrays = BatchArrays(
         ext=np.zeros((4, B, 7)), routing=static.base_routing, mu=np.ones((B, 7)),
         group=static.group, alpha=static.alpha, cap_queue=np.full((B, 7), np.inf), dt=1.0,

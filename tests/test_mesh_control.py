@@ -155,6 +155,51 @@ def test_masked_lanes_decide_none_in_jit_and_twin():
         assert row.reason == "padded lane"
 
 
+@pytest.mark.parametrize("keyed", [False, True], ids=["pooled", "keyed"])
+@pytest.mark.parametrize("compact", [None, True], ids=["dense", "compact"])
+def test_make_decide_jax_one_device_mesh_is_bitwise(compact, keyed):
+    """The decide wrapped in ``shard_map`` over one device equals the
+    unwrapped decide bit for bit, dense and compacted, with and without
+    keyed operators, over a cold tick and a perturbed one."""
+    import dataclasses
+
+    scens = _scens(4, seed=13)
+    r = ScenarioRunner(scens, tick_interval=5.0, backend="jax")
+    static, params = r.static, r._params()
+    b, n = static.batch, static.n
+    if keyed:
+        hot = np.full((b, n), np.nan)
+        hot[:, 1] = np.where(static.active[:, 1], 0.4, np.nan)
+        static = dataclasses.replace(static, hot=hot)
+        assert static.keyed
+    rng = np.random.default_rng(2)
+    lam = np.abs(rng.normal(2.0, 0.6, (b, n)))
+    mu = np.abs(rng.normal(6.0, 0.5, (b, n))) + 1.0
+    drop = np.zeros((b, n))
+    lam0 = np.abs(rng.normal(2.0, 0.5, b))
+    k = np.where(static.active, 2, 0).astype(np.int64)
+    lam2 = lam.copy()
+    lam2[1] *= 1.5
+
+    outs = []
+    for mesh in (None, fleet_mesh(1)):
+        decide = ctl.make_decide_jax(static, params, mesh=mesh, compact=compact)
+        cache = (decide.init_cache(),) if compact else ()
+        ticks = []
+        for lam_t in (lam, lam2):
+            out = decide(lam_t, mu, drop, lam0, k, *cache)
+            if compact:
+                cache = out[2:]
+            ticks.append(jax.tree.leaves(out))
+        outs.append(ticks)
+    assert len(outs[0][0]) == len(outs[1][0]) >= 5 + keyed
+    for ref, got in zip(*outs):
+        for a, g in zip(ref, got):
+            a, g = np.asarray(a), np.asarray(g)
+            assert a.dtype == g.dtype
+            np.testing.assert_array_equal(a, g)
+
+
 def test_fused_loop_padded_lanes_never_influence_real_ones():
     """Run the fused loop at B and at B+2 (two inert pad lanes, no mesh):
     the real lanes' decisions and aggregates must be bitwise unchanged,

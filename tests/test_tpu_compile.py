@@ -4,7 +4,9 @@ Interpret-mode parity tests cannot see the TPU's lowering rules (block
 tiling, Mosaic's vector-only ops, scoped VMEM).  These tests ask the
 installed TPU compiler to compile each main-path kernel at fleet widths
 for a described v5e chip, which needs no chip attached, and check that
-the compiled program holds the kernel itself (``tpu_custom_call``).
+the compiled program holds the kernel itself (``tpu_custom_call``).  The
+whole decide at the cells' shapes is lowered for the same chip (Pallas
+to Mosaic included); compiling it takes about a minute on a CPU host.
 
 The topology is described inside a fixture, never at import, so every
 pytest-xdist worker collects the same tests and only the worker that runs
@@ -15,13 +17,16 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.decide_fused import kernel as dk
+import repro.core.controller as ctl
+from repro.api import AppGraph, Edge, OpDef
 from repro.kernels.erlang_c import kernel as ek
 from repro.kernels.gain_topr import kernel as gk
 from repro.kernels.l2_match import kernel as lk
@@ -68,14 +73,6 @@ CASES = {
     "erlang_c_S114688_k44": (
         functools.partial(ek.erlang_b_table_pallas, k_hi=44), [((16384 * 7,), F32)],
     ),
-    "decide_fused_B16384_k64": (
-        functools.partial(dk.batch_decide_pallas, k_hi=K),
-        [((B, N), F32)] * 6 + [((B,), I32)],
-    ),
-    "decide_fused_B1_k512": (
-        functools.partial(dk.batch_decide_pallas, k_hi=512),
-        [((1, N), F32)] * 6 + [((1,), I32)],
-    ),
     "l2_match_count": (
         lambda a, b, valid: lk.match_count_pallas(a, b, valid, 0.5),
         [((512, 128), F32), ((256, 128), F32), ((512,), jnp.bool_)],
@@ -119,3 +116,60 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in text
     if name.startswith("gain_topr"):  # the layout follows B: lanes from 128 up
         assert ("gain_topr_lanes" in text) == (shapes[0][0][0] >= 128)
+
+
+def _chain(mus, hot=None):
+    """A chain of operators; ``hot[i]`` makes operator i keyed."""
+    hot = hot or {}
+    names = [f"op{i}" for i in range(len(mus))]
+    ops = [OpDef(nm, mu=m, scaling="keyed" if i in hot else "replica",
+                 hot_share=hot.get(i)) for i, (nm, m) in enumerate(zip(names, mus))]
+    return AppGraph(ops, [Edge(a, b) for a, b in zip(names, names[1:])], {names[0]: 1.0})
+
+
+# name -> (one lane's graph, k_max, compacted); the cells' decide shapes:
+# VLD (N = 3, k_max 22) dense and compacted, NEXmark (N = 7, k_max 44,
+# four keyed operators) dense.
+DECIDES = {
+    "decide_vld_B16384_k22": (_chain((2.0, 5.0, 50.0)), 22, False),
+    "decide_vld_compact_B16384_k22": (_chain((2.0, 5.0, 50.0)), 22, True),
+    "decide_nexmark_keyed_B16384_k44": (
+        _chain((2000.0, 12000.0, 5000.0, 5000.0, 2000.0, 1200.0, 5000.0),
+               {1: 0.3, 2: 0.3, 4: 0.3, 5: 0.3}), 44, False,
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DECIDES))
+def test_decide_lowers_for_v5e(name, one_chip):
+    """The decide core with its statics as operands at B = 16384: each
+    program holds the Erlang table and the lane top-R kernels."""
+    graph, k_max, compact = DECIDES[name]
+    static = ctl.ControllerStatic.from_graphs([graph])
+    full = lambda v: np.full(1, v)
+    params = ctl.ControllerParams(
+        t_max=full(np.nan), k_max=np.full(1, k_max, np.int64), headroom=full(1.1),
+        scale_in_hysteresis=full(0.8), min_improvement=full(0.05),
+        horizon_seconds=full(300.0), allocator=("table",),
+    )
+    b, n = 16384, static.n
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(
+            shape, jax.dtypes.canonicalize_dtype(dtype), sharding=one_chip
+        )
+
+    st = {k: sds((b,) + v.shape[1:], v.dtype)
+          for k, v in ctl._decide_statics(static, params).items()}
+    args = [sds((b, n), F32)] * 3 + [sds((b,), F32), sds((b, n), I32)]
+    core = ctl._make_decide_core(n, k_max, 5.0, interpret=False, force_kernel=True)
+    if compact:
+        core = ctl._make_compact_decide(core, b, ctl._resolve_ladder(True, b))
+        cache = jax.eval_shape(lambda: ctl.init_decide_cache(b, n, dtype=F32))
+        args.append(jax.tree.map(lambda x: sds(x.shape, x.dtype), cache))
+    text = jax.jit(core).lower(st, *args).as_text()
+    kernels = re.findall(r'kernel_name = "([^"]+)"', text)
+    rungs = len(ctl._resolve_ladder(True, b)) if compact else 1
+    assert kernels.count("_erlang_b_kernel") == rungs, kernels
+    assert kernels.count("gain_topr_lanes") == rungs, kernels
+    assert static.keyed == ("keyed" in name)
