@@ -14,8 +14,9 @@ bulk along three axes:
   against one topology via table gather.
 * **tenant/scenario batch axis** — :func:`solve_traffic_batch` solves the
   Jackson traffic equations for a ``[B, N]`` batch of ``lam0`` vectors
-  (optionally a ``[B, N, N]`` batch of routing matrices) in one
-  ``linalg.solve``.
+  (optionally a ``[B, N, N]`` batch of routing matrices) at once: one
+  ``linalg.solve`` in numpy, a lanes-minor Gaussian elimination unrolled
+  over the static N in jnp.
 
 Backends and the fallback rule (DESIGN.md §12): every function has a
 float64 **numpy** implementation — the default off-TPU, and the one the
@@ -359,16 +360,57 @@ def expected_sojourn_batch_jax(top: Topology, k_batch, *, interpret: bool = Fals
 
 def solve_traffic_batch_jax(lam0_batch, routing):
     """jnp traffic-equation solve for ``[B, N]`` externals (shared or
-    per-scenario routing) via ``jnp.linalg.solve``."""
+    per-scenario routing) by Gaussian elimination with partial pivoting,
+    unrolled over the static N with the lanes on the minor axis.
+
+    ``A = I - P^T`` is held as an ``[N, N, B]`` block, so every step is
+    elementwise lane work that XLA fuses, where a batched LU of
+    ``[B, N, N]`` works through the lanes nearly one at a time.  Pivots are
+    ``getrf``'s: the largest ``|A[r, k]|`` over ``r >= k``, the lowest row
+    on ties.  A zero pivot gives ``inf``/``nan`` by division, so a singular
+    lane comes out non-finite, as from the LU.
+    """
     import jax.numpy as jnp
 
     lam0 = jnp.atleast_2d(jnp.asarray(lam0_batch))
     p = jnp.asarray(routing, dtype=lam0.dtype)
-    n = lam0.shape[-1]
-    pt = jnp.swapaxes(p, -1, -2)
-    a = jnp.eye(n, dtype=lam0.dtype) - pt
-    if a.ndim == 3:
-        lam = jnp.linalg.solve(a, lam0[..., None])[..., 0]
-    else:
-        lam = jnp.linalg.solve(a, lam0.T).T
+    b, n = lam0.shape
+    pt = jnp.transpose(p, (2, 1, 0)) if p.ndim == 3 else jnp.broadcast_to(
+        p.T[:, :, None], (n, n, b)
+    )
+    # The trailing block left to eliminate, [N - k, N - k, B], and its
+    # right-hand side, [N - k, B].
+    a = jnp.eye(n, dtype=lam0.dtype)[:, :, None] - pt
+    x = lam0.T
+    rows, rhs, inv = [], [], []
+    for k in range(n):
+        # The pivot by a strict compare down the column, not argmax, whose
+        # TPU reduction may compare in bfloat16.
+        mag = jnp.abs(a[:, 0])
+        best, piv = mag[0], jnp.zeros(mag.shape[1:], jnp.int32)
+        for r in range(1, n - k):
+            take = mag[r] > best
+            best = jnp.where(take, mag[r], best)
+            piv = jnp.where(take, r, piv)
+        hit = jnp.arange(n - k)[:, None] == piv
+        # Swap the pivot row in with selects (one term per lane survives
+        # the sum): a one-hot product would turn a singular lane's inf
+        # into nan in rows the swap never touched.
+        a_piv = jnp.where(hit[:, None], a, 0.0).sum(axis=0)
+        x_piv = jnp.where(hit, x, 0.0).sum(axis=0)
+        a_rest = jnp.where(hit[1:, None], a[:1], a[1:])
+        x_rest = jnp.where(hit[1:], x[:1], x[1:])
+        inv.append(1.0 / a_piv[0])
+        f = a_rest[:, 0] * inv[k]
+        a = a_rest[:, 1:] - f[:, None] * a_piv[None, 1:]
+        x = x_rest - f * x_piv
+        rows.append(a_piv[1:])
+        rhs.append(x_piv)
+    lam = [None] * n
+    for k in reversed(range(n)):
+        s = rhs[k]
+        for j in range(k + 1, n):
+            s = s - rows[k][j - k - 1] * lam[j]
+        lam[k] = s * inv[k]
+    lam = jnp.stack(lam, axis=-1)
     return jnp.where(jnp.abs(lam) < 1e-12, 0.0, lam)

@@ -17,8 +17,9 @@ a stateless controller that operates on ``[B, N]`` snapshot stacks:
   **bit-identical** to the pre-extraction scheduler.  ``DRSScheduler``
   is now a thin stateful shell over these functions.
 * **jit jax path** — :func:`make_decide_jax` compiles the whole decide
-  (batched Jackson solve via ``solve_traffic_batch_jax``, batched
-  offered-load clamping, one table pass through ``kernels/erlang_c``,
+  (batched Jackson solve, a lanes-minor Gaussian elimination in
+  ``solve_traffic_batch_jax``, batched offered-load clamping, one
+  table pass through ``kernels/erlang_c``,
   Program-4 allocation as a masked top-R selection through
   ``kernels/gain_topr``, vectorized improvement + cost gates) into ONE
   program over the ``[B, N]`` fleet; :func:`make_fused_loop` fuses it

@@ -179,6 +179,129 @@ def test_jax_batch_sojourn_and_traffic():
     np.testing.assert_allclose(tj, solve_traffic_batch(lam0_b, top.routing), rtol=1e-5)
 
 
+def _traffic_lanes(n, rng, lanes=16):
+    """``[5 * lanes, n, n]`` routings and ``[5 * lanes, n]`` externals: a
+    chain (VLD), a chain whose middle operator leaks into itself (FPD's
+    detector), a split into parallel operators joined again (NEXmark),
+    random substochastic lanes, and lanes whose operator 0 routes all it
+    gets back to itself, so the leading diagonal of I - P^T is 0 and the
+    first column needs a row swap (a rescaled routing may do that)."""
+    idx = np.arange(n)
+    chain = np.zeros((lanes, n, n))
+    chain[:, idx[:-1], idx[1:]] = rng.uniform(0.5, 1.5, (lanes, n - 1))
+    loop = chain.copy()
+    loop[:, n // 2, n // 2] = rng.uniform(0.1, 0.9, lanes)
+    split = np.zeros((lanes, n, n))
+    if n > 2:
+        split[:, 0, 1:-1] = rng.uniform(0.5, 1.5, (lanes, n - 2))
+        split[:, 1:-1, -1] = rng.uniform(0.5, 1.5, (lanes, n - 2))
+    else:
+        split = chain.copy()
+    rand = rng.random((lanes, n, n)) * (rng.random((lanes, n, n)) < 0.5)
+    rand *= rng.uniform(0.2, 0.95, (lanes, n, 1)) / np.maximum(
+        rand.sum(axis=-1, keepdims=True), 1e-9
+    )
+    swap = rand.copy()
+    if n > 1:
+        swap[:, 0, 0] = 1.0
+        swap[:, 0, 1] = rng.uniform(0.2, 0.5, lanes)
+        swap[:, 1, 0] = rng.uniform(0.2, 0.5, lanes)
+    p = np.concatenate([chain, loop, split, rand, swap])
+    lam0 = rng.uniform(0.0, 20.0, (p.shape[0], n))
+    return p, lam0
+
+
+@pytest.mark.parametrize("shared", [False, True], ids=["per_lane", "shared"])
+@pytest.mark.parametrize("n", [1, 2, 3, 7, 8])
+def test_jax_traffic_solve_matches_float64(n, shared):
+    """The elimination solves every lane as float64 ``numpy.linalg.solve``
+    does: to 1e-9 under x64 and to the float32 LU's own tolerance in
+    float32, with per-lane routing and with one routing shared by all."""
+    p, lam0 = _traffic_lanes(n, np.random.default_rng(n))
+    cases = [p] if not shared else [p[i] for i in range(0, p.shape[0], 16)]
+    solve = jax.jit(solve_traffic_batch_jax)
+    for routing in cases:
+        a = np.eye(n) - np.swapaxes(routing, -1, -2)
+        if shared:
+            want = np.linalg.solve(a, lam0.T).T
+        else:
+            want = np.linalg.solve(a, lam0[..., None])[..., 0]
+        want[np.abs(want) < 1e-12] = 0.0
+        with jax.enable_x64(True):
+            got = np.asarray(solve(jnp.asarray(lam0), jnp.asarray(routing)))
+        assert got.dtype == np.float64
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-9)
+        got32 = np.asarray(solve(lam0.astype(np.float32), routing.astype(np.float32)))
+        assert got32.dtype == np.float32
+        np.testing.assert_allclose(got32, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["f32", "x64"])
+def test_jax_traffic_solve_flags_singular_lanes(x64):
+    """Closed cycles whose rows sum to 1 have no solution: their lanes come
+    out non-finite or negative (what the decide's ``solve_bad`` reads), and
+    the jit decide codes them ``infeasible`` as the float64 twin does,
+    while the healthy lanes beside them stay finite and decide alike."""
+    from repro.core import controller as ctl
+    from repro.core.measurer import MeasurementBatch
+
+    cycle = np.roll(np.eye(3), 1, axis=1)  # 0 -> 1 -> 2 -> 0
+    split = np.array([[0.0, 0.25, 0.75], [0.0, 0.0, 1.0], [1.0, 0.0, 0.0]])
+    chain = vld_top().routing
+    p = np.stack([chain, cycle, 0.5 * chain, split])
+    lam0 = np.array([[13.0, 0, 0], [5.0, 0, 0], [7.0, 1.0, 0], [2.0, 0, 0]])
+    with jax.enable_x64(x64):
+        lam = np.asarray(jax.jit(solve_traffic_batch_jax)(lam0, p))
+    bad = ~np.isfinite(lam).all(axis=-1) | (lam < 0).any(axis=-1)
+    np.testing.assert_array_equal(bad, [False, True, False, True])
+    np.testing.assert_allclose(
+        lam[[0, 2]], solve_traffic_batch(lam0[[0, 2]], p[[0, 2]]), rtol=1e-6
+    )
+
+    b = p.shape[0]
+    full = lambda v: np.full(b, v)
+    static = ctl.ControllerStatic(
+        p, np.zeros((b, 3), bool), np.zeros((b, 3)), np.ones((b, 3), bool),
+        np.ones((b, 3)), np.full(b, 3), (("extract", "match", "agg"),) * b,
+    )
+    params = ctl.ControllerParams(
+        t_max=full(np.nan), k_max=np.full(b, 24), headroom=full(1.1),
+        scale_in_hysteresis=full(0.8), min_improvement=full(0.05),
+        horizon_seconds=full(300.0), allocator=("table",) * b,
+    )
+    lam_hat = np.array([[13.0, 13, 13], [5, 5, 5], [7, 4.5, 2.25], [2, 2, 2]])
+    mu = np.tile([2.0, 5.0, 50.0], (b, 1))
+    k = np.tile([8, 4, 1], (b, 1))
+    drop = np.zeros_like(lam_hat)
+    lam0_hat = lam0.sum(axis=-1)
+    rows = ctl.tick_batch(
+        MeasurementBatch(lam_hat, mu, lam0_hat, full(np.nan), 0.0, drop), k, static,
+        params,
+    ).rows
+    with jax.enable_x64(x64):
+        code, k_next, et_cur, _, applied = map(
+            np.asarray,
+            ctl.make_decide_jax(static, params)(lam_hat, mu, drop, lam0_hat, k),
+        )
+    np.testing.assert_array_equal(code, [r.code for r in rows])
+    assert [r.action for r in rows][1::2] == ["infeasible", "infeasible"]
+    np.testing.assert_array_equal(k_next, [r.k_next for r in rows])
+    np.testing.assert_array_equal(applied, [r.applied for r in rows])
+    assert np.isfinite(et_cur[[0, 2]]).all()
+
+
+@pytest.mark.parametrize("shared", [False, True], ids=["per_lane", "shared"])
+@pytest.mark.parametrize("n", [3, 7])
+def test_jax_traffic_solve_lowers_without_custom_call(n, shared):
+    """The solve is plain elementwise lane work: its lowering holds no
+    custom call (``jnp.linalg.solve`` lowers to a batched LU and
+    triangular solves, ``lapack_sgetrf_ffi`` / ``lapack_strsm_ffi`` on
+    the CPU)."""
+    routing = jnp.zeros((n, n) if shared else (64, n, n))
+    text = jax.jit(solve_traffic_batch_jax).lower(jnp.ones((64, n)), routing).as_text()
+    assert "custom_call" not in text
+
+
 def test_jax_table_is_vmappable():
     """Batch of tenant arrival vectors through one vmapped table build."""
     mus = jnp.asarray([2.0, 5.0, 50.0])
